@@ -17,6 +17,7 @@ import argparse
 import inspect
 import json
 import os
+import sys
 import time
 
 
@@ -290,6 +291,7 @@ def main(argv=None) -> None:
         with open("results/bench.json") as f:
             all_rows = json.load(f)
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in BENCHES:
         if args.only and args.only not in name:
             continue
@@ -302,11 +304,17 @@ def main(argv=None) -> None:
             rows, us, derived = fn(**kwargs)
             all_rows[name] = rows
             print(f"{name},{us:.1f},{derived}", flush=True)
-        except Exception as e:  # surface, don't mask
+        except Exception as e:  # report every bench, then fail the run
+            failed.append(name)
             print(f"{name},0.0,ERROR:{type(e).__name__}:{e}", flush=True)
     with open("results/bench.json", "w") as f:
         json.dump(all_rows, f, indent=1, default=str)
+    if failed:
+        print(f"{len(failed)} benchmark(s) failed: {', '.join(failed)}",
+              file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -11,23 +11,13 @@ then injects a mid-run power cap on the fastest device at engine step N so
 the variability-drift detector has something to catch.
 """
 import argparse
-import dataclasses
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_smoke_config
-from repro.core import (
-    DeviceFleet,
-    GEMConfig,
-    profile_fleet,
-    setup_speeds,
-    simulator_measure_fn,
-)
-from repro.models import init_params
-from repro.serving import EngineConfig, ServingEngine
+from repro.core import GEMConfig, setup_speeds
+from repro.launch.serve import build_engine, model_config, simulated_profile
+from repro.serving import EngineConfig
 from repro.sharding import host_policy
 
 
@@ -49,26 +39,11 @@ def main():
                          "device at this engine step (0 = never)")
     args = ap.parse_args()
 
-    cfg = dataclasses.replace(
-        get_smoke_config(args.arch), decode_capacity_factor=4.0
-    )
-    policy = host_policy()
-    params, _ = init_params(cfg, jax.random.PRNGKey(0), policy, jnp.float32)
-
-    # emulated 4-device fleet + Step-2 profile (tile=1 so the smoke model's
+    cfg = model_config(args.arch, smoke=True)
+    # simulated 4-device fleet + Step-2 profile (tile=1 so the smoke model's
     # small per-step counts still differentiate placements)
-    speeds = setup_speeds(args.variability, 4)
-
-    def fleet_profile(sp):
-        fleet = DeviceFleet.from_speeds(sp, tile=1, tile_time=40e-6)
-        return profile_fleet(
-            simulator_measure_fn(fleet), 4, max_tokens=512, tile=1, repeats=5
-        ).profile
-
-    profile = fleet_profile(speeds)
-
-    eng = ServingEngine(
-        params, cfg, policy,
+    eng = build_engine(
+        cfg, host_policy(),
         EngineConfig(
             max_batch=8, max_len=128,
             gem=GEMConfig(trace_length=16, num_restarts=10),
@@ -77,7 +52,7 @@ def main():
             moe_backend=args.moe_backend,
             online=args.online,
         ),
-        profile=profile, num_devices=4,
+        variability=args.variability, num_devices=4, tile=1,
     )
 
     rng = np.random.default_rng(0)
@@ -87,14 +62,15 @@ def main():
 
     t0 = time.perf_counter()
     if args.online and args.slowdown_at > 0:
+        speeds = setup_speeds(args.variability, 4)
         slow = speeds.copy()
         slow[int(np.argmax(slow))] /= 2.0
-        slow_profile = fleet_profile(slow)
+        slow_profile = simulated_profile(slow, tile=1)
         steps = 0
         while eng.scheduler.has_work() and steps < 10_000:
             if steps == args.slowdown_at:
                 eng.set_true_profile(slow_profile)
-                print(f"[step {steps}] injected 2x slowdown on device "
+                print(f"[step {steps}] injected 2x slowdown on simulated device "
                       f"{int(np.argmax(speeds))}")
             eng.step()
             steps += 1

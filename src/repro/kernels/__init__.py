@@ -8,10 +8,10 @@
 ``shard_map`` over the (data, model) mesh so each device computes its local
 (E_v/16, C, D) shard; ``collective`` moves expert-weight rows between those
 shards with ppermute (the migration plane's swap/broadcast data plane);
-``compat`` resolves jax-version differences (``CompilerParams`` vs
-``TPUCompilerParams``, the ``shard_map`` home) and the per-backend interpret
-default; ``ops`` wraps both kernels with that detection (interpret=True on
-CPU); ``ref`` holds the pure-jnp oracles the tests allclose against.
+``compat`` builds the Mosaic compiler params and holds the per-backend
+interpret default; ``ops`` wraps both kernels with that detection
+(interpret=True on CPU); ``ref`` holds the pure-jnp oracles the tests
+allclose against.
 """
 from .collective import (
     CollectiveStats,
@@ -19,7 +19,7 @@ from .collective import (
     broadcast_expert_row,
     swap_expert_rows,
 )
-from .compat import auto_interpret, get_shard_map, pallas_compiler_params
+from .compat import auto_interpret, pallas_compiler_params
 from .ops import moe_ffn, moe_ffn_ref, topk_router, topk_router_ref
 from .sharded import moe_ffn_sharded, topk_router_sharded
 
@@ -28,7 +28,6 @@ __all__ = [
     "apply_row_sources",
     "auto_interpret",
     "broadcast_expert_row",
-    "get_shard_map",
     "pallas_compiler_params",
     "moe_ffn",
     "moe_ffn_ref",

@@ -67,7 +67,6 @@ from ..online.migration import (
     RowTransfer,
     lower_row_sources,
 )
-from .compat import get_shard_map
 
 __all__ = [
     "CollectiveStats",
@@ -104,13 +103,8 @@ class CollectiveStats:
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    sm = get_shard_map()
-    try:
-        return sm(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:  # jax ≥ 0.6 renamed check_rep → check_vma
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _round_tables(rnd: list[RowTransfer], num_shards: int):

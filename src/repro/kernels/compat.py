@@ -1,15 +1,7 @@
-"""Version-portable Pallas TPU shims shared by every kernel in this package.
+"""Pallas TPU helpers shared by every kernel in this package.
 
-jax renamed the Mosaic compiler-params dataclass across releases:
-``pltpu.TPUCompilerParams`` (jax ≤ 0.4.x / 0.5.x) became
-``pltpu.CompilerParams`` (0.6+). Kernels written against one spelling break
-on the other with an ``AttributeError`` at trace time — exactly the failure
-mode that took out the whole kernel path on this container's jax. All
-kernels therefore build their compiler params through
-:func:`pallas_compiler_params`, which resolves the spelling *at call time*
-(not import time) so a jax upgrade — or a test monkeypatching the module —
-is picked up without re-importing the kernels.
-
+:func:`pallas_compiler_params` builds the Mosaic compiler params (dimension
+semantics plus an optional scoped-VMEM limit) for ``pallas_call``.
 ``auto_interpret`` lives here too: every kernel entry point defaults to
 ``interpret=True`` off-TPU so the same call sites are CPU-testable.
 """
@@ -19,11 +11,9 @@ import jax
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
-    "compiler_params_cls",
     "pallas_compiler_params",
     "auto_interpret",
     "resolve_interpret",
-    "get_shard_map",
     "round_up",
 ]
 
@@ -38,41 +28,17 @@ def round_up(n: int, m: int) -> int:
     """
     return -(-n // m) * m
 
-_SPELLINGS = ("CompilerParams", "TPUCompilerParams")
 
+def pallas_compiler_params(dimension_semantics, *,
+                           vmem_limit_bytes: int | None = None):
+    """Compiler params carrying ``dimension_semantics`` for ``pallas_call``.
 
-def compiler_params_cls():
-    """The Mosaic compiler-params class under whichever name this jax has."""
-    for name in _SPELLINGS:
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    raise AttributeError(
-        "jax.experimental.pallas.tpu exposes none of "
-        f"{_SPELLINGS} — unsupported jax version {jax.__version__}"
+    ``vmem_limit_bytes`` raises the scoped-VMEM budget Mosaic may use for
+    the kernel's pipelined blocks (``None`` keeps the compiler default)."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics),
+        vmem_limit_bytes=vmem_limit_bytes,
     )
-
-
-def pallas_compiler_params(dimension_semantics):
-    """Compiler params carrying ``dimension_semantics`` for ``pallas_call``."""
-    return compiler_params_cls()(
-        dimension_semantics=tuple(dimension_semantics)
-    )
-
-
-def get_shard_map():
-    """``shard_map`` under whichever home this jax version gives it.
-
-    ``jax.experimental.shard_map.shard_map`` (≤ 0.4.x/0.5.x) graduated to
-    ``jax.shard_map`` (0.6+). Resolved at call time, like the compiler-params
-    spelling above, so a jax upgrade is picked up without re-import.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
 
 
 def auto_interpret() -> bool:

@@ -17,9 +17,13 @@ axis is the contraction of the second GEMM and accumulates into the output
 block (zeroed at the first F step). All operands are tiled into VMEM via
 BlockSpecs; accumulation is fp32 in the output ref, cast once at the end.
 
-VMEM budget per step (bf16): x (block_c·D) + Wg,Wu (2·D·block_f) +
-Wd (block_f·D) + out fp32 (block_c·D) — e.g. D=4096, block_c=128,
-block_f=256: ≈ 1 + 4 + 2 + 2 MB ≈ 9 MB < 16 MB v5e VMEM.
+VMEM budget per step (:func:`vmem_bytes`): the pipeline double-buffers
+every block — x (block_c·D), Wg, Wu (2·D·block_f), Wd (block_f·D) and the
+fp32 output (block_c·D) — plus the fp32 down-projection result and the
+(block_c, block_f) hidden activations. Granite's prefill tile (block_c=1024,
+D=1536, block_f=128) needs ≈ 30 MB, above Mosaic's default scoped limit
+(16 MiB on v5e), so the kernel raises ``vmem_limit_bytes`` to what its blocks need (v5e has
+128 MiB of VMEM).
 
 **Skinny decode row tile.** Decode capacities are tiny (C≈4 on decode_32k),
 so an 8-row ``block_c`` floor pads the row dim 100%. ``block_c`` may drop to
@@ -45,6 +49,21 @@ __all__ = ["moe_ffn_pallas", "SKINNY_BLOCK_C"]
 # f32 sublane minimum (8) are register-padded by Mosaic but still halve the
 # row-dim memory traffic at decode's C≈4 capacities.
 SKINNY_BLOCK_C = 4
+
+_MiB = 1 << 20
+# Mosaic's own scratch rides on top of the blocks and is not counted by
+# vmem_bytes: headroom for it, and a floor so small tiles keep a generous
+# limit (far inside the 128 MiB of VMEM on v5e)
+_VMEM_HEADROOM = 8 * _MiB
+_VMEM_FLOOR = 32 * _MiB
+
+
+def vmem_bytes(block_c: int, block_f: int, D: int, itemsize: int) -> int:
+    """VMEM one grid step of :func:`moe_ffn_pallas` holds, in bytes."""
+    in_blocks = (block_c * D + 3 * D * block_f) * itemsize
+    out_block = block_c * D * 4
+    temps = block_c * D * 4 + 3 * block_c * block_f * 4
+    return 2 * (in_blocks + out_block) + temps
 
 
 def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref):
@@ -102,7 +121,12 @@ def moe_ffn_pallas(
         out_specs=pl.BlockSpec((1, block_c, D), lambda e, c, f: (e, c, 0)),
         out_shape=jax.ShapeDtypeStruct((E, C, D), jnp.float32),
         compiler_params=pallas_compiler_params(
-            ("parallel", "parallel", "arbitrary")
+            ("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(
+                vmem_bytes(block_c, block_f, D, x_e.dtype.itemsize)
+                + _VMEM_HEADROOM,
+                _VMEM_FLOOR,
+            ),
         ),
         interpret=interpret,
     )(x_e, w_gate, w_up, w_down)

@@ -19,9 +19,8 @@ doesn't divide the model-axis extent (every device then redundantly computes
 all experts, correct but unsharded, with the caller warning once).
 
 ``mesh=None`` short-circuits to the direct single-device kernel calls, so
-host smoke tests and the mesh path share one call site. ``check_rep=False``
-throughout: ``pallas_call`` carries no replication rule, and newer jax
-spells the flag ``check_vma`` — ``_shard_map`` resolves that.
+host smoke tests and the mesh path share one call site. ``check_vma=False``
+throughout: ``pallas_call`` carries no replication rule.
 
 Both entry points are **differentiable**: the Pallas kernel runs the
 forward, and a ``custom_vjp`` supplies the backward as plain GSPMD jnp
@@ -37,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import get_shard_map, round_up as _round_up
+from .compat import round_up as _round_up
 from .moe_gemm import SKINNY_BLOCK_C, moe_ffn_pallas
 from .topk_router import topk_router_pallas
 
@@ -59,13 +58,8 @@ def effective_block_c(block_c: int, C: int) -> int:
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    sm = get_shard_map()
-    try:
-        return sm(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:  # jax ≥ 0.6 renamed check_rep → check_vma
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def moe_ffn_sharded(

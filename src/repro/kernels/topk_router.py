@@ -12,8 +12,8 @@ Switch-style load-balance loss needs — ``mean_probs = probs_sum / T`` and
 ``density = counts / T`` — so the caller never re-materializes the (T, E)
 probability matrix just for the aux loss. Padding rows (ragged T rounded up
 to ``block_t``) are masked out of both reductions by the static row bound,
-making the sums exact. Each grid step writes its (1, E) partial into a
-(num_blocks, E) output; the wrapper reduces over blocks, and the shard_map
+making the sums exact. Each grid step writes its (1, 1, E) partial into a
+(num_blocks, 1, E) output; the wrapper reduces over blocks, and the shard_map
 caller (``kernels.sharded``) reduces the per-data-shard partials the same
 way.
 """
@@ -31,31 +31,37 @@ __all__ = ["topk_router_pallas"]
 
 
 def _softmax_topk(logits, k: int):
-    """(T, E) f32 logits → probs, renormed top-k gates (T, k), ids (T, k)."""
+    """(T, E) f32 logits → probs, renormed top-k gates (T, k), ids (T, k),
+    and the (T, E) mask of the selected experts."""
     T, E = logits.shape
     m = jnp.max(logits, axis=-1, keepdims=True)
     e = jnp.exp(logits - m)
     probs = e / jnp.sum(e, axis=-1, keepdims=True)
 
     eidx = jax.lax.broadcasted_iota(jnp.int32, (T, E), 1)
+    # column j of the outputs is written by a lane-iota select: Mosaic has
+    # no scatter, so ``.at[:, j].set`` cannot lower
+    kidx = jax.lax.broadcasted_iota(jnp.int32, (T, k), 1)
     work = probs
     gates = jnp.zeros((T, k), jnp.float32)
     ids = jnp.zeros((T, k), jnp.int32)
     for j in range(k):  # k is small and static: unrolled selection
-        best = jnp.max(work, axis=-1)  # (T,)
+        best = jnp.max(work, axis=-1, keepdims=True)  # (T, 1)
         # lowest expert id among ties (matches lax.top_k tie-breaking)
-        is_best = work >= best[:, None]
-        best_id = jnp.min(jnp.where(is_best, eidx, E), axis=-1)
-        gates = gates.at[:, j].set(best)
-        ids = ids.at[:, j].set(best_id.astype(jnp.int32))
-        work = jnp.where(eidx == best_id[:, None], -jnp.inf, work)
+        best_id = jnp.min(
+            jnp.where(work >= best, eidx, E), axis=-1, keepdims=True
+        )
+        gates = jnp.where(kidx == j, best, gates)
+        ids = jnp.where(kidx == j, best_id, ids)
+        work = jnp.where(eidx == best_id, -jnp.inf, work)
     gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
-    return probs, gates, ids
+    # probs are ≥ 0, so exactly the selected experts were knocked to -inf
+    return probs, gates, ids, work == -jnp.inf
 
 
 def _router_kernel(logits_ref, gates_ref, ids_ref, *, k: int):
     logits = logits_ref[...].astype(jnp.float32)  # (block_t, E)
-    _, gates, ids = _softmax_topk(logits, k)
+    _, gates, ids, _ = _softmax_topk(logits, k)
     gates_ref[...] = gates
     ids_ref[...] = ids
 
@@ -67,20 +73,19 @@ def _router_stats_kernel(
     pid = pl.program_id(0)
     logits = logits_ref[...].astype(jnp.float32)  # (block_t, E)
     T, E = logits.shape
-    probs, gates, ids = _softmax_topk(logits, k)
+    probs, gates, ids, chosen = _softmax_topk(logits, k)
     gates_ref[...] = gates
     ids_ref[...] = ids
     # mask padding rows (global row ≥ t_valid) out of the reductions: the
     # pad rows are zero logits → uniform 1/E probs that would bias the sums
     row = pid * block_t + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
     valid = row < t_valid  # (T, 1)
-    psum_ref[...] = jnp.sum(jnp.where(valid, probs, 0.0), axis=0)[None]
-    eidx = jax.lax.broadcasted_iota(jnp.int32, (T, E), 1)
-    cnt = jnp.zeros((E,), jnp.int32)
-    for j in range(k):
-        sel = (eidx == ids[:, j][:, None]) & valid
-        cnt = cnt + jnp.sum(sel.astype(jnp.int32), axis=0)
-    cnt_ref[...] = cnt[None]
+    psum_ref[...] = jnp.sum(
+        jnp.where(valid, probs, 0.0), axis=0, keepdims=True
+    )[None]
+    cnt_ref[...] = jnp.sum(
+        jnp.where(valid & chosen, 1, 0), axis=0, keepdims=True
+    ).astype(jnp.int32)[None]
 
 
 @functools.partial(
@@ -134,15 +139,19 @@ def topk_router_pallas(logits, k: int, *, block_t: int = 256,
         ),
         grid=grid,
         in_specs=[pl.BlockSpec((block_t, E), lambda t: (t, 0))],
+        # per-block partials are (1, 1, E) blocks of an (n_blocks, 1, E)
+        # array: a block's last two dims must equal the array's (or tile
+        # by (8, 128)), which a (1, E) block of an (n_blocks, E) array
+        # breaks as soon as there is more than one row block
         out_specs=row_specs + [
-            pl.BlockSpec((1, E), lambda t: (t, 0)),
-            pl.BlockSpec((1, E), lambda t: (t, 0)),
+            pl.BlockSpec((1, 1, E), lambda t: (t, 0, 0)),
+            pl.BlockSpec((1, 1, E), lambda t: (t, 0, 0)),
         ],
         out_shape=row_shapes + [
-            jax.ShapeDtypeStruct((n_blocks, E), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, E), jnp.int32),
+            jax.ShapeDtypeStruct((n_blocks, 1, E), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, E), jnp.int32),
         ],
         compiler_params=pallas_compiler_params(("parallel",)),
         interpret=interpret,
     )(padded)
-    return gates[:T], ids[:T], psum.sum(axis=0), cnt.sum(axis=0)
+    return gates[:T], ids[:T], psum.sum(axis=(0, 1)), cnt.sum(axis=(0, 1))

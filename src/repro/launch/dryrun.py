@@ -101,16 +101,10 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # jax ≤ 0.4.x: list of dicts
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         coll = collective_stats(hlo)
         walk = compute_stats(hlo)
-        # jaxlib ≤ 0.4.x has no peak_memory_in_bytes on CompiledMemoryStats;
-        # the temp size is the XLA heap proxy there (an upper bound on peak)
-        xla_peak = getattr(mem, "peak_memory_in_bytes", None)
-        if xla_peak is None:
-            xla_peak = mem.temp_size_in_bytes
+        xla_peak = mem.peak_memory_in_bytes
         mem_d = {
             "argument_bytes": int(mem.argument_size_in_bytes),
             "output_bytes": int(mem.output_size_in_bytes),

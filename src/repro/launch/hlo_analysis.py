@@ -21,7 +21,9 @@ from __future__ import annotations
 
 import re
 
-__all__ = ["collective_stats", "COLLECTIVES"]
+__all__ = [
+    "collective_stats", "arrays_shaped", "expert_weight_shapes", "COLLECTIVES",
+]
 
 COLLECTIVES = (
     "all-gather", "all-reduce", "reduce-scatter", "all-to-all",
@@ -400,3 +402,31 @@ def collective_stats(text: str, *, default_trip: int = 1) -> dict:
         "total_wire_bytes": float(sum(wire.values())),
         "unresolved_loops": len(unresolved),
     }
+
+
+def arrays_shaped(text: str, dtype: str, shapes) -> list[str]:
+    """Instructions of ``text``, fusion bodies included, whose result is a
+    ``dtype`` array ending in one of ``shapes`` (leading dims, such as a
+    stacked layer axis, are ignored): e.g. an f32 copy of a bf16 weight."""
+    want = {tuple(s) for s in shapes}
+    hits = []
+    for line in text.splitlines():
+        m = _DEF_RE.match(line)
+        if m is None:
+            continue
+        r = re.match(r"(\w+)\[([\d,]*)\]", m.group(2))
+        if r is None or r.group(1) != dtype:
+            continue
+        dims = tuple(int(d) for d in r.group(2).split(",") if d)
+        if any(dims[len(dims) - len(w):] == w for w in want
+               if len(dims) >= len(w)):
+            hits.append(line.strip())
+    return hits
+
+
+def expert_weight_shapes(config, model_shards: int = 1) -> list[tuple]:
+    """Per-chip (E_v, D, F) and (E_v, F, D) shapes of one layer's expert
+    weights with the experts split over ``model_shards`` chips."""
+    Ev = config.num_experts * config.expert_tp // model_shards
+    D, F = config.d_model, config.expert_d_ff // config.expert_tp
+    return [(Ev, D, F), (Ev, F, D)]

@@ -8,21 +8,14 @@ init, smoke tests and benches see the real single device.
 from __future__ import annotations
 
 import jax
-
-try:  # jax ≥ 0.5: explicit-sharding axis types exist; Auto keeps GSPMD
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: meshes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh", "policy_for"]
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    # Auto axes keep GSPMD propagation (no explicit-sharding types)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

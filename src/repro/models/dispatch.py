@@ -262,7 +262,12 @@ def route(
     E = config.num_experts
     k = config.experts_per_token
     N = Gd * Ng
-    logits = jnp.einsum("gnd,de->gne", xg, router_w).astype(jnp.float32)
+    # f32 logits from f32 operands: a bf16 dot rounded and then upcast is one
+    # XLA may compute at f32 or not depending on what it fuses with, so the
+    # backends' programs would select on different logits and flip near-ties
+    logits = jnp.einsum(
+        "gnd,de->gne", xg.astype(jnp.float32), router_w.astype(jnp.float32)
+    )
     if backend == "pallas":
         data_spec, _ = policy.moe_shard_spec(Gd, E * config.expert_tp)
         gates, ids, probs_sum, counts = topk_router_sharded(
@@ -587,13 +592,23 @@ def expert_compute(
                 w_gate = jnp.pad(w_gate, ((0, pad), (0, 0), (0, 0)))
                 w_up = jnp.pad(w_up, ((0, pad), (0, 0), (0, 0)))
                 w_down = jnp.pad(w_down, ((0, pad), (0, 0), (0, 0)))
-        h_gate = jnp.einsum("gecd,edf->gecf", xe, w_gate)
-        h_up = jnp.einsum("gecd,edf->gecf", xe, w_up)
-        h = jax.nn.silu(h_gate) * h_up
+        # fp32 accumulation and hidden activations, rounded once before the
+        # down projection: the kernel's (and kernels/ref.py's) math, so the
+        # two backends agree in bf16 instead of drifting apart over layers.
+        # The TPU compiler folds the operand upcasts into the bf16 MXU dot
+        # (the same program as preferred_element_type=f32, which XLA:CPU
+        # cannot run inside a scan)
+        f32 = jnp.float32
+        xf = xe.astype(f32)
+        h_gate = jnp.einsum("gecd,edf->gecf", xf, w_gate.astype(f32))
+        h_up = jnp.einsum("gecd,edf->gecf", xf, w_up.astype(f32))
+        h = (jax.nn.silu(h_gate) * h_up).astype(xe.dtype)
         h = policy.constrain(
             h, b, pad_spec if pad_spec is not None else expert_spec, None, None
         )
-        y_e = jnp.einsum("gecf,efd->gecd", h, w_down)
+        y_e = jnp.einsum(
+            "gecf,efd->gecd", h.astype(f32), w_down.astype(f32)
+        ).astype(xe.dtype)
         if pad_spec is not None:
             y_e = y_e[:, :Ev]
     y_e = y_e * plan.dispatch_gate[..., None].astype(y_e.dtype)

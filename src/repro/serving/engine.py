@@ -411,7 +411,9 @@ class ServingEngine:
             )
             if profile is not None:
                 self.planner.set_profile(profile)
-            self.placements = identity_placement(config, config.num_layers)
+            self.placements = self._device_tables(
+                identity_placement(config, config.num_layers)
+            )
             Ev = config.num_experts * config.expert_tp
             self.current_placements = [
                 Placement.linear(Ev, nd) for _ in range(config.num_layers)
@@ -851,8 +853,18 @@ class ServingEngine:
     def _replica_tables(self, rplacements) -> jnp.ndarray:
         """(L, E_v, P) replica-split router tables for the data plane."""
         P = self.ecfg.replication.pattern_period
-        return jnp.asarray(
+        return self._device_tables(
             np.stack([rp.replica_table(P) for rp in rplacements])
+        )
+
+    def _device_tables(self, tables) -> jax.Array:
+        """Router tables as decode operands: replicated over the policy's
+        mesh, the sharding migration-swapped tables come back with — an
+        operand whose sharding changed would retrace the decode step."""
+        if self.policy.mesh is None:
+            return jnp.asarray(tables)
+        return jax.device_put(
+            tables, self.policy.named(*(None,) * np.ndim(tables))
         )
 
     def _install_replicated_pool(self, rplacements) -> None:
@@ -1298,7 +1310,7 @@ class ServingEngine:
                 cost_mx,
             )
             if self.controller.replicated:
-                self.placements = jnp.asarray(
+                self.placements = self._device_tables(
                     self.controller.expert_to_slot_tables()
                 )
                 self.current_rplacements = list(
@@ -1318,7 +1330,7 @@ class ServingEngine:
                 # shares: rebuild the split tables NOW, not at the next
                 # migration batch — otherwise the data plane keeps routing
                 # by the stale shares while step costs assume the new ones
-                self.placements = jnp.asarray(
+                self.placements = self._device_tables(
                     self.controller.expert_to_slot_tables()
                 )
                 self.current_rplacements = list(
@@ -1437,24 +1449,7 @@ class ServingEngine:
                 "placement_applied": self.placement_applied,
             }
 
-        tokens = jnp.asarray(self.last_token[:, None])
-        if self.paged:
-            # per-row lengths + block tables: ragged slots attend at their
-            # true positions through the paged view
-            logits, new_caches, moe_aux = self._decode(
-                self.params, self.caches, jnp.asarray(self.cur_len),
-                jnp.asarray(self.block_tables), tokens, self.placements,
-                self._shed_operand(),
-            )
-        else:
-            # single shared cur_len is not enough for ragged slots: use
-            # per-slot max — attention masks per-slot validity through
-            # cache zero panels (the dense fallback's approximation)
-            cur = jnp.asarray(int(self.cur_len.max()))
-            logits, new_caches, moe_aux = self._decode(
-                self.params, self.caches, cur, tokens, self.placements,
-                self._shed_operand(),
-            )
+        logits, new_caches, moe_aux = self._decode(*self._decode_args())
         self.caches = new_caches
         next_tokens = np.asarray(
             sample(logits, temperature=self.ecfg.temperature,
@@ -1535,7 +1530,33 @@ class ServingEngine:
             "finished": len(self.finished),
             "sim_latency": sim_latency,
             "placement_applied": self.placement_applied,
+            "logits": logits,  # (max_batch, V) on device; idle rows too
         }
+
+    def _decode_args(self) -> tuple:
+        """Operands of the decode executable for the current slot state."""
+        tokens = jnp.asarray(self.last_token[:, None])
+        if self.paged:
+            # per-row lengths + block tables: ragged slots attend at their
+            # true positions through the paged view
+            return (
+                self.params, self.caches, jnp.asarray(self.cur_len),
+                jnp.asarray(self.block_tables), tokens, self.placements,
+                self._shed_operand(),
+            )
+        # single shared cur_len is not enough for ragged slots: use
+        # per-slot max — attention masks per-slot validity through
+        # cache zero panels (the dense fallback's approximation)
+        cur = jnp.asarray(int(self.cur_len.max()))
+        return (
+            self.params, self.caches, cur, tokens, self.placements,
+            self._shed_operand(),
+        )
+
+    def decode_hlo(self) -> str:
+        """Optimized HLO text of the decode executable compiled for the
+        current operands (``tpu_custom_call`` marks a Mosaic kernel)."""
+        return self._decode.lower(*self._decode_args()).compile().as_text()
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
         steps = 0

@@ -1,71 +1,9 @@
-"""Kernel compat layer: both CompilerParams spellings must keep both Pallas
-kernels importable AND runnable (interpret mode on CPU), so the next jax
-rename can't silently re-break the kernel path."""
-import dataclasses
-
+"""Kernel backend default and the compile-cache placement helper."""
 import jax
-import numpy as np
-import pytest
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import moe_ffn, moe_ffn_ref, topk_router, topk_router_ref
-from repro.kernels.compat import (
-    auto_interpret,
-    compiler_params_cls,
-    pallas_compiler_params,
-    resolve_interpret,
-)
-
-REAL_CLS = compiler_params_cls()
-SPELLINGS = ("CompilerParams", "TPUCompilerParams")
-
-
-@pytest.fixture(params=SPELLINGS)
-def spelled_pltpu(request, monkeypatch):
-    """Expose the real compiler-params class under exactly one spelling."""
-    # the kernels are jit'd: drop cached traces so each spelling re-resolves
-    jax.clear_caches()
-    for name in SPELLINGS:
-        monkeypatch.delattr(pltpu, name, raising=False)
-    monkeypatch.setattr(pltpu, request.param, REAL_CLS, raising=False)
-    yield request.param
-    jax.clear_caches()
-
-
-def test_resolves_either_spelling(spelled_pltpu):
-    assert compiler_params_cls() is REAL_CLS
-    params = pallas_compiler_params(("parallel",))
-    assert params.dimension_semantics == ("parallel",)
-
-
-def test_missing_both_spellings_raises(monkeypatch):
-    for name in SPELLINGS:
-        monkeypatch.delattr(pltpu, name, raising=False)
-    with pytest.raises(AttributeError, match="CompilerParams"):
-        compiler_params_cls()
-
-
-def test_moe_ffn_runs_under_either_spelling(spelled_pltpu):
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    E, C, D, F = 2, 16, 32, 64
-    x = jax.random.normal(ks[0], (E, C, D))
-    wg = jax.random.normal(ks[1], (E, D, F)) * 0.05
-    wu = jax.random.normal(ks[2], (E, D, F)) * 0.05
-    wd = jax.random.normal(ks[3], (E, F, D)) * 0.05
-    got = moe_ffn(x, wg, wu, wd, block_c=16, block_f=32, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(moe_ffn_ref(x, wg, wu, wd)),
-        rtol=2e-5, atol=2e-5,
-    )
-
-
-def test_topk_router_runs_under_either_spelling(spelled_pltpu):
-    logits = jax.random.normal(jax.random.PRNGKey(1), (48, 8))
-    g1, i1 = topk_router(logits, 2, block_t=16, interpret=True)
-    g2, i2 = topk_router_ref(logits, 2)
-    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
-                               rtol=2e-5, atol=2e-5)
+from repro.kernels.compat import auto_interpret, resolve_interpret
+from repro.launch import compile_cache
+from repro.launch.compile_cache import CACHE_ENV, compile_cache_dir
 
 
 def test_auto_interpret_on_cpu():
@@ -76,14 +14,21 @@ def test_auto_interpret_on_cpu():
     assert resolve_interpret(False) is False
 
 
-def test_dataclass_cache_not_stale(monkeypatch):
-    """Resolution happens at call time: a swap after import is honoured."""
+def test_compile_cache_dir_env_wins_else_fixed_repo_path(monkeypatch, tmp_path):
+    monkeypatch.setenv(CACHE_ENV, str(tmp_path))
+    assert compile_cache_dir() == str(tmp_path)
+    monkeypatch.delenv(CACHE_ENV)
+    first = compile_cache_dir()
+    assert first == compile_cache_dir() == str(compile_cache.DEFAULT_CACHE_DIR)
+    repo = compile_cache.DEFAULT_CACHE_DIR.parent
+    assert (repo / "src" / "repro" / "launch" / "compile_cache.py").exists()
+    gitignore = (repo / ".gitignore").read_text().split()
+    assert "/.jax_cache/" in gitignore
 
-    @dataclasses.dataclass
-    class Fake:
-        dimension_semantics: tuple = ()
-
-    for name in SPELLINGS:
-        monkeypatch.delattr(pltpu, name, raising=False)
-    monkeypatch.setattr(pltpu, "TPUCompilerParams", Fake, raising=False)
-    assert isinstance(pallas_compiler_params(("arbitrary",)), Fake)
+    monkeypatch.setenv(CACHE_ENV, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
