@@ -53,6 +53,11 @@ build_dispatch/expert_compute/combine pipeline for ``backend="dense_ref"``;
 it still consumes :class:`RouterOutput`, so all three backends share the
 staged structure.
 
+Each stage traces under a ``jax.named_scope`` of its own name, so every
+operation it lowers to carries ``route`` / ``build_dispatch`` /
+``expert_compute`` / ``combine`` in its HLO ``op_name`` metadata, and a
+profiler trace of a compiled program can be split by stage.
+
 The structs are registered pytrees: they cross ``jax.jit`` / ``lax.scan``
 boundaries intact, and :class:`MoEAux` is what the layer stack scans and the
 serving engine reads for Step-1 traces (it also supports ``aux["..."]``
@@ -247,6 +252,7 @@ _register(
 )
 
 
+@jax.named_scope("route")
 def route(
     xg, router_w, config: ModelConfig, policy: ShardingPolicy, *, backend: str
 ) -> RouterOutput:
@@ -317,6 +323,7 @@ def _rank_in_group(slots, num_slots: int):
     return jnp.take(pos_sorted, inv), group_sizes
 
 
+@jax.named_scope("build_dispatch")
 def build_dispatch(
     router: RouterOutput,
     expert_to_slot,
@@ -518,6 +525,7 @@ def build_dispatch(
     )
 
 
+@jax.named_scope("expert_compute")
 def expert_compute(
     xg,
     plan: DispatchPlan,
@@ -615,6 +623,7 @@ def expert_compute(
     return policy.constrain(y_e, b, expert_spec, None, None)
 
 
+@jax.named_scope("combine")
 def combine(
     y_e,
     plan: DispatchPlan,

@@ -166,28 +166,30 @@ def _scan_or_unroll(f, init, xs, mode: str):
 def _attn_block_train(x, lp, placement_l, config: ModelConfig,
                       policy: ShardingPolicy, *, return_cache: bool,
                       capacity_factor=None):
-    h = rms_norm(x, lp["ln1"], config.norm_eps)
-    a, cache = attention_train(
-        h, lp["attn"], config, policy, return_cache=return_cache
-    )
-    if cache is not None:
-        cache = {"k": cache.k, "v": cache.v}
-    x = x + a
-    h2 = rms_norm(x, lp["ln2"], config.norm_eps)
-    aux = None
-    if config.is_moe:
-        h2 = policy.act_bsd(h2)  # gather tokens across the model axis
-        y, aux = moe_layer(
-            h2, lp["moe"], placement_l, config, policy,
-            capacity_factor=capacity_factor, seq_sharded_out=True,
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["ln1"], config.norm_eps)
+        a, cache = attention_train(
+            h, lp["attn"], config, policy, return_cache=return_cache
         )
-    else:
-        h2 = policy.act_bsd(h2)
-        y = gated_mlp(
-            h2, lp["mlp"], activation=config.mlp_activation, policy=policy,
-            seq_sharded_out=True,
-        )
-    x = policy.act_seq_sharded(x + y)
+        if cache is not None:
+            cache = {"k": cache.k, "v": cache.v}
+        x = x + a
+    with jax.named_scope("ffn"):
+        h2 = rms_norm(x, lp["ln2"], config.norm_eps)
+        aux = None
+        if config.is_moe:
+            h2 = policy.act_bsd(h2)  # gather tokens across the model axis
+            y, aux = moe_layer(
+                h2, lp["moe"], placement_l, config, policy,
+                capacity_factor=capacity_factor, seq_sharded_out=True,
+            )
+        else:
+            h2 = policy.act_bsd(h2)
+            y = gated_mlp(
+                h2, lp["mlp"], activation=config.mlp_activation,
+                policy=policy, seq_sharded_out=True,
+            )
+        x = policy.act_seq_sharded(x + y)
     return x, cache, aux
 
 
@@ -311,9 +313,10 @@ def _stack_forward(x, params, placements, config: ModelConfig,
         body = jax.checkpoint(body)
     if placements is None:
         placements = identity_placement(config, config.num_layers)
-    x, (caches, auxes) = _scan_or_unroll(
-        body, x, (blocks, placements), stack_mode
-    )
+    with jax.named_scope("layer_scan"):
+        x, (caches, auxes) = _scan_or_unroll(
+            body, x, (blocks, placements), stack_mode
+        )
     moe_aux = auxes if config.is_moe else None
     return x, ({"attn": caches} if return_cache else None), moe_aux
 
@@ -377,9 +380,10 @@ def prefill(params, batch, config: ModelConfig, policy: ShardingPolicy,
         x, params, placements, config, policy, return_cache=True,
         remat=False, stack_mode=stack_mode,
     )
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    last = policy.constrain(x[:, -1:], policy.batch, None, None)
-    logits = lm_logits(last, params, config, policy, mode="decode")
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], config.norm_eps)
+        last = policy.constrain(x[:, -1:], policy.batch, None, None)
+        logits = lm_logits(last, params, config, policy, mode="decode")
     return logits[:, 0], caches
 
 
@@ -558,35 +562,41 @@ def decode_step(params, caches, cur_len, tokens, config: ModelConfig,
             placements = identity_placement(config, config.num_layers)
 
         def layer_body(xc, lp, placement_l, cache, shed_l):
-            h = rms_norm(xc, lp["ln1"], config.norm_eps)
-            if block_tables is not None:
-                a, (new_k, new_v) = attention_decode_paged(
-                    h, lp["attn"], cache["k"], cache["v"], block_tables,
-                    cur_len, config, policy,
-                )
-                new_c = AttnCache(new_k, new_v)
-            else:
-                a, new_c = attention_decode(
-                    h, lp["attn"], AttnCache(cache["k"], cache["v"]), cur_len,
-                    config, policy,
-                )
-            xc = xc + a
-            h2 = rms_norm(xc, lp["ln2"], config.norm_eps)
-            if config.is_moe:
-                y, aux = moe_layer(
-                    h2, lp["moe"], placement_l, config, policy,
-                    capacity_factor=config.decode_capacity_factor,
-                    shed_enable=shed_l,
-                )
-            else:
-                aux = _moe_aux_zero(config) if config.is_moe else 0.0
-                y = gated_mlp(
-                    h2, lp["mlp"], activation=config.mlp_activation,
-                    policy=policy,
-                )
-            if config.is_moe and aux is None:
-                aux = _moe_aux_zero(config)
-            return xc + y, ({"k": new_c.k, "v": new_c.v}, aux)
+            # named scopes split a profile of the compiled step by layer
+            # part; what is left directly under "layer_scan" is the scan's
+            # own operand and carry handling
+            with jax.named_scope("attention"):
+                h = rms_norm(xc, lp["ln1"], config.norm_eps)
+                if block_tables is not None:
+                    a, (new_k, new_v) = attention_decode_paged(
+                        h, lp["attn"], cache["k"], cache["v"], block_tables,
+                        cur_len, config, policy,
+                    )
+                    new_c = AttnCache(new_k, new_v)
+                else:
+                    a, new_c = attention_decode(
+                        h, lp["attn"], AttnCache(cache["k"], cache["v"]),
+                        cur_len, config, policy,
+                    )
+                xc = xc + a
+            with jax.named_scope("ffn"):
+                h2 = rms_norm(xc, lp["ln2"], config.norm_eps)
+                if config.is_moe:
+                    y, aux = moe_layer(
+                        h2, lp["moe"], placement_l, config, policy,
+                        capacity_factor=config.decode_capacity_factor,
+                        shed_enable=shed_l,
+                    )
+                else:
+                    aux = _moe_aux_zero(config) if config.is_moe else 0.0
+                    y = gated_mlp(
+                        h2, lp["mlp"], activation=config.mlp_activation,
+                        policy=policy,
+                    )
+                if config.is_moe and aux is None:
+                    aux = _moe_aux_zero(config)
+                xc = xc + y
+            return xc, ({"k": new_c.k, "v": new_c.v}, aux)
 
         if shed_enables is None:
             # pre-shed operand tuple: the traced program (and therefore
@@ -603,13 +613,15 @@ def decode_step(params, caches, cur_len, tokens, config: ModelConfig,
 
             xs = (blocks, placements, shed_enables, caches["attn"])
 
-        x, (new_attn, auxes) = _scan_or_unroll(body, x, xs, decode_mode)
+        with jax.named_scope("layer_scan"):
+            x, (new_attn, auxes) = _scan_or_unroll(body, x, xs, decode_mode)
         new_caches = {"attn": new_attn}
         if config.is_moe:
             moe_aux = auxes
 
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    logits = lm_logits(x, params, config, policy, mode="decode")
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], config.norm_eps)
+        logits = lm_logits(x, params, config, policy, mode="decode")
     return logits[:, 0], new_caches, moe_aux
 
 

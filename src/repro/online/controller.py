@@ -478,20 +478,23 @@ class OnlineController:
             self.planner.observe_step(layer, counts[layer])
 
         reason: str | None = None
-        if (
-            self.config.online
-            and self.planned
-            and observed_device_latency is not None
-            and not self.migrating
-        ):
-            predicted = self.predicted_device_latency(counts)
-            if self.var_detector.update(observed_device_latency, predicted):
-                self._rescale_profile()
-                decision.profile_rescaled = True
-                reason = "variability-drift"
-        if self.config.online and self.planned and not self.migrating:
-            if self.load_detector.update(counts) and reason is None:
-                reason = "load-drift"
+        with self.telemetry.span("controller.drift", step=self._step):
+            if (
+                self.config.online
+                and self.planned
+                and observed_device_latency is not None
+                and not self.migrating
+            ):
+                predicted = self.predicted_device_latency(counts)
+                if self.var_detector.update(
+                    observed_device_latency, predicted
+                ):
+                    self._rescale_profile()
+                    decision.profile_rescaled = True
+                    reason = "variability-drift"
+            if self.config.online and self.planned and not self.migrating:
+                if self.load_detector.update(counts) and reason is None:
+                    reason = "load-drift"
 
         self._step += 1
 
@@ -650,49 +653,53 @@ class OnlineController:
         return None
 
     def _replan(self, decision: StepDecision, reason: str) -> None:
-        window = self.planner.config.trace_length
-        traces = [c.trace(window) for c in self.planner.collectors]
-        layers = self._staggered_layers(reason)
-        if self.replicated:
-            rtarget = self._plan_rplacements(window, layers)
-            # skipped layers reuse the live ReplicatedPlacement, whose
-            # slot_layout() IS the live layout — zero moves by construction
-            target_layouts = [rp.slot_layout() for rp in rtarget]
-            schedule = plan_replica_migration(
-                self.slot_layouts, target_layouts, self.config.migration
-            )
-            spd = self.num_slots // self.planner.num_devices
-            cur_score = sum(
-                replicated_score(t, self.profile, rp)
-                for t, rp in zip(traces, self.current_rplacements)
-            )
-            tgt_score = sum(
-                replicated_score(t, self.profile, rp)
-                for t, rp in zip(traces, rtarget)
-            )
-        else:
-            target = self._plan_placements(window, layers)
-            # migration targets for skipped layers must be the *raw live*
-            # layout, not the derived Placement: a Placement canonicalises
-            # expert order within each device, and after a truncated
-            # migration the live layout may not be canonical — diffing
-            # against the Placement would emit spurious within-device moves
-            migration_target = (
-                list(target) if layers is None else [
-                    target[i] if i in layers else self.slot_layouts[i]
-                    for i in range(len(target))
-                ]
-            )
-            schedule = plan_migration(
-                self.slot_layouts, migration_target, self.config.migration
-            )
-            cur_score = sum(
-                score(t, self.profile, p)
-                for t, p in zip(traces, self.current_placements)
-            )
-            tgt_score = sum(
-                score(t, self.profile, p) for t, p in zip(traces, target)
-            )
+        with self.telemetry.span("controller.replan", step=self._step,
+                                 reason=reason):
+            window = self.planner.config.trace_length
+            traces = [c.trace(window) for c in self.planner.collectors]
+            layers = self._staggered_layers(reason)
+            if self.replicated:
+                rtarget = self._plan_rplacements(window, layers)
+                # skipped layers reuse the live ReplicatedPlacement, whose
+                # slot_layout() IS the live layout — zero moves by
+                # construction
+                target_layouts = [rp.slot_layout() for rp in rtarget]
+                schedule = plan_replica_migration(
+                    self.slot_layouts, target_layouts, self.config.migration
+                )
+                spd = self.num_slots // self.planner.num_devices
+                cur_score = sum(
+                    replicated_score(t, self.profile, rp)
+                    for t, rp in zip(traces, self.current_rplacements)
+                )
+                tgt_score = sum(
+                    replicated_score(t, self.profile, rp)
+                    for t, rp in zip(traces, rtarget)
+                )
+            else:
+                target = self._plan_placements(window, layers)
+                # migration targets for skipped layers must be the *raw
+                # live* layout, not the derived Placement: a Placement
+                # canonicalises expert order within each device, and after
+                # a truncated migration the live layout may not be
+                # canonical — diffing against the Placement would emit
+                # spurious within-device moves
+                migration_target = (
+                    list(target) if layers is None else [
+                        target[i] if i in layers else self.slot_layouts[i]
+                        for i in range(len(target))
+                    ]
+                )
+                schedule = plan_migration(
+                    self.slot_layouts, migration_target, self.config.migration
+                )
+                cur_score = sum(
+                    score(t, self.profile, p)
+                    for t, p in zip(traces, self.current_placements)
+                )
+                tgt_score = sum(
+                    score(t, self.profile, p) for t, p in zip(traces, target)
+                )
         first_plan = not self.planned
         self.planned = True
         self._last_plan_step = self._step

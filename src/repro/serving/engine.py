@@ -639,64 +639,68 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def _write_slot(self, slot: int, req: Request) -> None:
         """Prefill one request and install its caches into the pool slot."""
-        batch = {"tokens": jnp.asarray(req.prompt[None, :])}
-        logits, caches = self._prefill(self.params, batch, self.placements)
-        L = req.prompt_len
+        with self.telemetry.span("engine.prefill", step=self.step_count,
+                                 uid=req.uid, tokens=req.prompt_len):
+            batch = {"tokens": jnp.asarray(req.prompt[None, :])}
+            logits, caches = self._prefill(self.params, batch, self.placements)
+            L = req.prompt_len
 
-        def install(pool, new):
-            # pool (..., max_batch, S_pool, ...), new (..., 1, L, ...); the
-            # leading layer dims match — write [slot, :L].
-            if pool.ndim == new.ndim and new.shape[-3:] == pool.shape[-3:]:
-                return pool.at[..., slot, :, :, :].set(new[..., 0, :, :, :])
-            return pool
+            def install(pool, new):
+                # pool (..., max_batch, S_pool, ...), new (..., 1, L, ...); the
+                # leading layer dims match — write [slot, :L].
+                if pool.ndim == new.ndim and new.shape[-3:] == pool.shape[-3:]:
+                    return pool.at[..., slot, :, :, :].set(new[..., 0, :, :, :])
+                return pool
 
-        # attention caches: (L?, B, S, KV, hd) — pad new to pool length
-        def install_attn(pool, new):
-            pad = pool.shape[-3] - new.shape[-3]
-            new = jnp.pad(
-                new, [(0, 0)] * (new.ndim - 3) + [(0, pad), (0, 0), (0, 0)]
-            )
-            idx = (slice(None),) * (new.ndim - 4) + (slot,)
-            return pool.at[idx].set(new[..., 0, :, :, :])
+            # attention caches: (L?, B, S, KV, hd) — pad new to pool length
+            def install_attn(pool, new):
+                pad = pool.shape[-3] - new.shape[-3]
+                new = jnp.pad(
+                    new, [(0, 0)] * (new.ndim - 3) + [(0, pad), (0, 0), (0, 0)]
+                )
+                idx = (slice(None),) * (new.ndim - 4) + (slot,)
+                return pool.at[idx].set(new[..., 0, :, :, :])
 
-        c = self.caches
-        if "attn" in c:
-            c["attn"]["k"] = install_attn(c["attn"]["k"], caches["attn"]["k"])
-            c["attn"]["v"] = install_attn(c["attn"]["v"], caches["attn"]["v"])
-        for key in ("ssm", "ssm_staged", "ssm_tail"):
-            if key in c:
-                for part in c[key]:
-                    pool, new = c[key][part], caches[key][part]
-                    bdim = pool.ndim - new.ndim + 1  # batch axis in pool
-                    idx = (slice(None),) * (new.ndim - (pool.ndim - bdim) - 1)
-                    # batch axis position: state (..., B, nh, hd, N) → -4;
-                    # conv (..., B, cw-1, C) → -3
-                    if part == "state":
-                        c[key][part] = pool.at[..., slot, :, :, :].set(
-                            new[..., 0, :, :, :]
-                        )
-                    else:
-                        c[key][part] = pool.at[..., slot, :, :].set(
-                            new[..., 0, :, :]
-                        )
-        self.cur_len[slot] = req.prompt_len
-        self.last_token[slot] = int(np.asarray(jnp.argmax(logits[0])))
-        self.installed[slot] = True
+            c = self.caches
+            if "attn" in c:
+                c["attn"]["k"] = install_attn(c["attn"]["k"], caches["attn"]["k"])
+                c["attn"]["v"] = install_attn(c["attn"]["v"], caches["attn"]["v"])
+            for key in ("ssm", "ssm_staged", "ssm_tail"):
+                if key in c:
+                    for part in c[key]:
+                        pool, new = c[key][part], caches[key][part]
+                        bdim = pool.ndim - new.ndim + 1  # batch axis in pool
+                        idx = (slice(None),) * (new.ndim - (pool.ndim - bdim) - 1)
+                        # batch axis position: state (..., B, nh, hd, N) → -4;
+                        # conv (..., B, cw-1, C) → -3
+                        if part == "state":
+                            c[key][part] = pool.at[..., slot, :, :, :].set(
+                                new[..., 0, :, :, :]
+                            )
+                        else:
+                            c[key][part] = pool.at[..., slot, :, :].set(
+                                new[..., 0, :, :]
+                            )
+            self.cur_len[slot] = req.prompt_len
+            self.last_token[slot] = int(np.asarray(jnp.argmax(logits[0])))
+            self.installed[slot] = True
 
     def _install_paged_slot(self, slot: int, req: Request) -> None:
         """Prefill one request and scatter its KV into its owned blocks."""
-        batch = {"tokens": jnp.asarray(req.prompt[None, :])}
-        logits, caches = self._prefill(self.params, batch, self.placements)
-        table = self.kv_pool.block_table(req.uid)
-        blocks = jnp.asarray(np.asarray(table, np.int32))
-        c = self.caches["attn"]
-        c["k"] = self._paged_install(c["k"], caches["attn"]["k"], blocks)
-        c["v"] = self._paged_install(c["v"], caches["attn"]["v"], blocks)
-        self.block_tables[slot, :] = 0
-        self.block_tables[slot, : len(table)] = table
-        self.cur_len[slot] = req.prompt_len
-        self.last_token[slot] = int(np.asarray(jnp.argmax(logits[0])))
-        self.installed[slot] = True
+        with self.telemetry.span("engine.prefill", step=self.step_count,
+                                 uid=req.uid, tokens=req.prompt_len):
+            batch = {"tokens": jnp.asarray(req.prompt[None, :])}
+            logits, caches = self._prefill(self.params, batch, self.placements)
+            table = self.kv_pool.block_table(req.uid)
+            blocks = jnp.asarray(np.asarray(table, np.int32))
+            c = self.caches["attn"]
+            c["k"] = self._paged_install(c["k"], caches["attn"]["k"], blocks)
+            c["v"] = self._paged_install(c["v"], caches["attn"]["v"], blocks)
+            self.block_tables[slot, :] = 0
+            self.block_tables[slot, : len(table)] = table
+            self.cur_len[slot] = req.prompt_len
+            self.last_token[slot] = int(np.asarray(jnp.argmax(logits[0])))
+            self.installed[slot] = True
 
     def _prefill_phase(self) -> float:
         """Advance prefill for admitted-but-uninstalled slots; returns the
@@ -1173,25 +1177,32 @@ class ServingEngine:
         record_step_metrics(self.telemetry, sr, self.step_count)
 
     def _maybe_replan(self) -> None:
+        """The one-shot plan, once its trace window has filled."""
+        if self._replan_due():
+            # step() has already counted the step this call ends
+            with self.telemetry.span("engine.replan",
+                                     step=self.step_count - 1):
+                self._replan_once()
+
+    def _replan_due(self) -> bool:
         if (
             self.planner is None
             or self.controller is not None  # online mode: drift, not a timer
             or self.placement_applied
             or self.profile is None
         ):
-            return
+            return False
         threshold = (
             self.ecfg.replan_after
             if self.ecfg.replan_after is not None
             else self.ecfg.gem.trace_length
         )
-        if self.step_count < threshold:
-            return
-        if not all(
+        return self.step_count >= threshold and all(
             c.num_steps >= self.ecfg.gem.trace_length
             for c in self.planner.collectors
-        ):
-            return
+        )
+
+    def _replan_once(self) -> None:
         if self.ecfg.placement_policy == "linear":
             self.placement_applied = True
             return
@@ -1235,10 +1246,12 @@ class ServingEngine:
                 moves=int(moves),
                 modeled_s=float(self._cost_model.cost(moves)),
             )
-            stats = self._retarget_replicated_pool(rplacements)
-            swap_cost = self._record_migration(
-                moves, self._cost_model.cost(moves), stats, None
-            )
+            with self.telemetry.span("engine.migrate",
+                                     step=self.step_count - 1, moves=moves):
+                stats = self._retarget_replicated_pool(rplacements)
+                swap_cost = self._record_migration(
+                    moves, self._cost_model.cost(moves), stats, None
+                )
             if self.sim_step_latencies:
                 self.sim_step_latencies[-1] += swap_cost
             self.sim_time += swap_cost
@@ -1253,20 +1266,22 @@ class ServingEngine:
         # slot_to_expert table, and the in-dispatch table swap inverts it
         # into expert_to_slot)
         slot_to_expert = np.stack([p.slot_to_expert() for p in placements])
-        stats = self._apply_migration_sources(
-            slot_to_expert.astype(np.int32), swap_tables=True
-        )
-        # the one-shot swap moves weights too: charge it to the step that
-        # performs it (unbudgeted, one batch), with the same cost model the
-        # online mode pays per batch — otherwise comparing the two modes'
-        # latency reports silently favours one-shot
         moves = sum(
             len(cur.moved_slots(new))
             for cur, new in zip(self.current_placements, placements)
         )
-        swap_cost = self._record_migration(
-            moves, self._cost_model.cost(moves), stats, None
-        )
+        with self.telemetry.span("engine.migrate",
+                                 step=self.step_count - 1, moves=moves):
+            stats = self._apply_migration_sources(
+                slot_to_expert.astype(np.int32), swap_tables=True
+            )
+            # the one-shot swap moves weights too: charge it to the step
+            # that performs it (unbudgeted, one batch), with the same cost
+            # model the online mode pays per batch — otherwise comparing
+            # the two modes' latency reports silently favours one-shot
+            swap_cost = self._record_migration(
+                moves, self._cost_model.cost(moves), stats, None
+            )
         if self.sim_step_latencies:
             self.sim_step_latencies[-1] += swap_cost
         self.sim_time += swap_cost
@@ -1297,18 +1312,18 @@ class ServingEngine:
             # batches are permutations, so the router tables ride the
             # same dispatch on device; replica batches are not and keep
             # the host-side table recompute from the controller's shares.
-            src = self.controller.dense_migration_sources(
-                decision.migration_step
-            )
-            stats = self._apply_migration_sources(
-                src, swap_tables=not self.controller.replicated
-            )
-            migration_charge = self._record_migration(
-                decision.migration_step.num_moves,
-                decision.migration_cost,
-                stats,
-                cost_mx,
-            )
+            moves = decision.migration_step.num_moves
+            with self.telemetry.span("engine.migrate", step=self.step_count,
+                                     moves=moves):
+                src = self.controller.dense_migration_sources(
+                    decision.migration_step
+                )
+                stats = self._apply_migration_sources(
+                    src, swap_tables=not self.controller.replicated
+                )
+                migration_charge = self._record_migration(
+                    moves, decision.migration_cost, stats, cost_mx,
+                )
             if self.controller.replicated:
                 self.placements = self._device_tables(
                     self.controller.expert_to_slot_tables()
@@ -1417,13 +1432,23 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def step(self) -> dict[str, Any]:
         """One engine iteration: ingest arrivals → admit → prefill-chunk →
-        decode → sample → bookkeeping (continuous batching)."""
-        self._ingest_arrivals()
+        decode → sample → bookkeeping (continuous batching). The step and
+        each of its phases run inside a wall-clock program span
+        (``engine.*``; ``telemetry/README.md``)."""
+        with self.telemetry.step_span(
+            "engine.step", self.step_count, active=self.scheduler.num_active
+        ):
+            return self._step()
+
+    def _step(self) -> dict[str, Any]:
         tel = self.telemetry
-        t0 = self.sim_time
-        can_admit = self._kv_admit if self.kv_pool is not None else None
-        for slot, req in self.scheduler.admit(can_admit=can_admit):
-            req.start_step = self.step_count
+        step = self.step_count
+        with tel.span("engine.admit", step=step):
+            self._ingest_arrivals()
+            t0 = self.sim_time
+            can_admit = self._kv_admit if self.kv_pool is not None else None
+            for slot, req in self.scheduler.admit(can_admit=can_admit):
+                req.start_step = self.step_count
 
         if not self.scheduler.active:
             return {"active": 0}
@@ -1449,41 +1474,50 @@ class ServingEngine:
                 "placement_applied": self.placement_applied,
             }
 
-        logits, new_caches, moe_aux = self._decode(*self._decode_args())
+        with tel.span("engine.dispatch", step=step):
+            logits, new_caches, moe_aux = self._decode(*self._decode_args())
         self.caches = new_caches
-        next_tokens = np.asarray(
-            sample(logits, temperature=self.ecfg.temperature,
-                   key=jax.random.PRNGKey(self.step_count))
-        )
-
-        # GEM Step-1: per-layer expert counts from the staged dispatch
-        # plane's MoEAux struct (scan-stacked RouterOutput.expert_counts)
-        sim_latency = prefill_charge + self.ecfg.other_time_per_step
-        if moe_aux is not None and self.planner is not None:
-            counts = np.asarray(moe_aux.expert_counts)  # (L, E)
-            counts_virt = np.repeat(counts, self.config.expert_tp, axis=1)
-            cost_mx = self._step_cost_matrix(counts_virt)
-            shed_latency = None
-            if self._shed_enables is not None:
-                # shedding changes what the fleet PAID (adjusted loads +
-                # transfer charge) but not what the control plane SEES:
-                # cost_mx below stays the un-shed matrix for the
-                # controller, attribution, and regret
-                shed_latency = self._shed_step(counts_virt, moe_aux, cost_mx)
-            if shed_latency is not None:
-                sim_latency += shed_latency
-            elif cost_mx is not None:
-                sim_latency += float(cost_mx.max(axis=1).sum())
-            self._observe_attribution(counts_virt)
-            self._observe_regret(counts_virt, cost_mx)
-            tel.counter("dispatch.dropped_tokens").inc(
-                int(np.asarray(moe_aux.dropped_tokens).sum())
+        observe = moe_aux is not None and self.planner is not None
+        with tel.span("engine.sync", step=step):
+            next_tokens = np.asarray(
+                sample(logits, temperature=self.ecfg.temperature,
+                       key=jax.random.PRNGKey(self.step_count))
             )
-            if self.controller is not None:
-                sim_latency += self._online_step(counts_virt, cost_mx)
-            else:
-                for layer in range(self.config.num_layers):
-                    self.planner.observe_step(layer, counts_virt[layer])
+            if observe:
+                # GEM Step-1: per-layer expert counts from the staged
+                # dispatch plane's MoEAux struct (scan-stacked
+                # RouterOutput.expert_counts)
+                counts = np.asarray(moe_aux.expert_counts)  # (L, E)
+                dropped = int(np.asarray(moe_aux.dropped_tokens).sum())
+
+        sim_latency = prefill_charge + self.ecfg.other_time_per_step
+        if observe:
+            with tel.span("engine.attribution", step=step):
+                counts_virt = np.repeat(counts, self.config.expert_tp, axis=1)
+                cost_mx = self._step_cost_matrix(counts_virt)
+                shed_latency = None
+                if self._shed_enables is not None:
+                    # shedding changes what the fleet PAID (adjusted loads
+                    # + transfer charge) but not what the control plane
+                    # SEES: cost_mx below stays the un-shed matrix for the
+                    # controller, attribution, and regret
+                    shed_latency = self._shed_step(
+                        counts_virt, moe_aux, cost_mx
+                    )
+                if shed_latency is not None:
+                    sim_latency += shed_latency
+                elif cost_mx is not None:
+                    sim_latency += float(cost_mx.max(axis=1).sum())
+                self._observe_attribution(counts_virt)
+            with tel.span("engine.regret", step=step):
+                self._observe_regret(counts_virt, cost_mx)
+            tel.counter("dispatch.dropped_tokens").inc(dropped)
+            with tel.span("engine.controller", step=step):
+                if self.controller is not None:
+                    sim_latency += self._online_step(counts_virt, cost_mx)
+                else:
+                    for layer in range(self.config.num_layers):
+                        self.planner.observe_step(layer, counts_virt[layer])
         tel.emit_span(
             "decode", self.sim_time, sim_latency - prefill_charge,
             step=self.step_count, active=int(self.installed.sum()),
@@ -1493,28 +1527,29 @@ class ServingEngine:
         # TTFT stamp needs it); advance by the decode remainder only
         self.sim_time += sim_latency - prefill_charge
 
-        done_slots = []
-        decoded = 0
-        for slot, req in list(self.scheduler.active.items()):
-            if not self.installed[slot]:
-                continue  # still prefilling (chunked): no token this step
-            tok = int(next_tokens[slot])
-            req.generated.append(tok)
-            decoded += 1
-            self.last_token[slot] = tok
-            self.cur_len[slot] += 1
-            if req.done or self.cur_len[slot] >= self.ecfg.max_len - 1:
-                req.finish_step = self.step_count
-                req.finish_time = self.sim_time
-                self.finished.append(req)
-                done_slots.append((slot, req))
-        for slot, req in done_slots:
-            self.scheduler.release(slot)
-            self.cur_len[slot] = 0
-            self.installed[slot] = False
-            if self.kv_pool is not None:
-                self.kv_pool.release(req.uid)
-                self.block_tables[slot, :] = 0
+        with tel.span("engine.finish", step=step):
+            done_slots = []
+            decoded = 0
+            for slot, req in list(self.scheduler.active.items()):
+                if not self.installed[slot]:
+                    continue  # still prefilling (chunked): no token yet
+                tok = int(next_tokens[slot])
+                req.generated.append(tok)
+                decoded += 1
+                self.last_token[slot] = tok
+                self.cur_len[slot] += 1
+                if req.done or self.cur_len[slot] >= self.ecfg.max_len - 1:
+                    req.finish_step = self.step_count
+                    req.finish_time = self.sim_time
+                    self.finished.append(req)
+                    done_slots.append((slot, req))
+            for slot, req in done_slots:
+                self.scheduler.release(slot)
+                self.cur_len[slot] = 0
+                self.installed[slot] = False
+                if self.kv_pool is not None:
+                    self.kv_pool.release(req.uid)
+                    self.block_tables[slot, :] = 0
 
         if decoded:
             tel.counter("engine.decode_tokens").inc(decoded)

@@ -1,5 +1,6 @@
 """Unified telemetry plane: metrics registry, simulated-clock span
-tracing, per-step straggler attribution, and Chrome-trace/JSONL export.
+tracing, wall-clock program spans on the profiler's clock, per-step
+straggler attribution, and Chrome-trace/JSONL export.
 
 See ``telemetry/README.md`` in this package for the event/metric schema
 reference and the versioning rule.

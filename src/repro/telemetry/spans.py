@@ -1,21 +1,23 @@
-"""Simulated-clock span tracing and the :class:`Telemetry` hub.
+"""The :class:`Telemetry` hub: simulated-clock events and wall-clock
+program spans.
 
-The serving engine runs on a *simulated* clock (``engine.sim_time``
-advances in discrete charges), so spans here are not wall-clock timers:
-a span's duration is whatever the instrumented plane says it charged.
-Two recording styles:
+Two clocks, kept apart:
 
-- ``with tel.span("decode_step", track="engine"):`` — context manager
-  for phases whose charge is applied while the span is open (the clock
-  callback is read at enter and exit).
-- ``tel.emit_span(name, start, dur, track=..., **args)`` — explicit
-  emission for phases whose charge is computed after the fact (e.g. the
-  decode charge is ``cost_mx.max(axis=1).sum()``, known only once the
-  step's cost matrix exists).
-
-Every span/instant becomes one structured event dict (the JSONL schema
-in :mod:`repro.telemetry.export`); ``track`` names the timeline it
-renders on in the Chrome trace ("engine", "device0".."deviceG-1").
+- **Simulated events.** The serving engine charges a *simulated* clock
+  (``engine.sim_time``) with the planner's model of each phase; those
+  charges are recorded as events with
+  ``tel.emit_span(name, start, dur, track=..., **args)`` (a phase whose
+  charge is known only after the fact, e.g. the decode charge
+  ``cost_mx.max(axis=1).sum()``) and ``tel.instant(name, **args)``.
+  Every span/instant becomes one structured event dict (the JSONL schema
+  in :mod:`repro.telemetry.export`); ``track`` names the timeline it
+  renders on in the Chrome trace ("engine", "device0".."deviceG-1").
+- **Program spans.** ``with tel.span(name, **args):`` times the host
+  work itself on the wall clock, as a ``jax.profiler.TraceAnnotation``:
+  it lands in the profiler's trace beside the device's operations, its
+  keyword args as event stats, and costs about a microsecond when no
+  profiler is attached. ``tel.step_span`` marks one engine step for the
+  profiler's step view. Program spans are never appended to ``events``.
 
 :class:`Telemetry` is the object the planes hold. It is **always
 constructed** — ``ServingEngine(..., telemetry=None)`` gets a disabled
@@ -23,13 +25,14 @@ instance — because the metrics registry doubles as the single source of
 truth for read-through attributes (``jit_trace_counts``,
 ``migration_records``) that must keep working with telemetry off.
 Only *event recording* (spans/instants, the export surface) is gated by
-``enabled``; registry instruments are pure host-side state and can never
-perturb tokens.
+``enabled``; registry instruments and program spans are pure host-side
+state and can never perturb tokens.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Callable
+
+import jax
 
 from .registry import Registry
 
@@ -37,7 +40,8 @@ __all__ = ["Telemetry"]
 
 
 class Telemetry:
-    """Per-run telemetry hub: registry + event log + simulated clock."""
+    """Per-run telemetry hub: registry + event log + simulated clock,
+    and the program's wall-clock spans."""
 
     def __init__(self, *, enabled: bool = True,
                  clock: Callable[[], float] | None = None):
@@ -81,19 +85,6 @@ class Telemetry:
             ev["args"] = args
         self.events.append(ev)
 
-    @contextlib.contextmanager
-    def span(self, name: str, *, track: str = "engine", **args):
-        """Context-manager span over the simulated clock."""
-        if not self.enabled:
-            yield
-            return
-        start = self.now()
-        try:
-            yield
-        finally:
-            self.emit_span(name, start, self.now() - start,
-                           track=track, **args)
-
     def instant(self, name: str, *, track: str = "engine",
                 ts: float | None = None, **args) -> None:
         """Record a zero-duration marker (preemption, drift fire, ...)."""
@@ -111,3 +102,21 @@ class Telemetry:
         self.migration_records.append(record)
         self.instant("migration", ts=record.get("sim_time"),
                      **{k: v for k, v in record.items() if k != "sim_time"})
+
+    # -- program spans (wall clock) -----------------------------------
+    @staticmethod
+    def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+        """Wall-clock program span: ``with tel.span("engine.regret",
+        step=7):`` records ``name`` with ``args`` in the profiler's trace
+        while one is being taken, and nothing otherwise."""
+        return jax.profiler.TraceAnnotation(name, **args)
+
+    @staticmethod
+    def step_span(name: str, step: int, **args
+                  ) -> jax.profiler.StepTraceAnnotation:
+        """The program span of one whole step, marked for the profiler's
+        step view (``step_num``) and carrying ``step`` like every other
+        program span."""
+        return jax.profiler.StepTraceAnnotation(
+            name, step_num=step, step=step, **args
+        )

@@ -99,7 +99,9 @@ def test_snapshot_is_deterministic():
 def test_span_records_simulated_clock():
     t = {"now": 1.0}
     tel = Telemetry(clock=lambda: t["now"])
-    with tel.span("step", track="engine", step=0):
+    tel.emit_span("step", 1.0, 0.5, track="engine", step=0)
+    # a wall-clock program span is the profiler's, never an event
+    with tel.span("engine.step", step=0):
         t["now"] = 1.5
     tel.emit_span("decode", 1.5, 0.25, track="engine")
     tel.instant("preempt", request=7)
