@@ -17,6 +17,15 @@ axis is the contraction of the second GEMM and accumulates into the output
 block (zeroed at the first F step). All operands are tiled into VMEM via
 BlockSpecs; accumulation is fp32 in the output ref, cast once at the end.
 
+**Stacked weights.** Given a ``layer`` index, the weights are the whole
+stack, (L, E, D, F) / (L, E, F, D), and the kernel reads that layer's
+blocks in place: the index is scalar-prefetched into SMEM and the weight
+BlockSpecs' index maps pick the layer from it. A layer scan then hands
+the kernel the stacked weights as they are, instead of slicing each
+layer's weights out into a buffer of their own first (a full extra read
+and write of every expert weight per step, which the compiler may also
+stage in VMEM ahead of the kernel).
+
 VMEM budget per step (:func:`vmem_bytes`): the pipeline double-buffers
 every block — x (block_c·D), Wg, Wu (2·D·block_f), Wd (block_f·D) and the
 fp32 output (block_c·D) — plus the fp32 down-projection result and the
@@ -40,6 +49,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .compat import pallas_compiler_params
 
@@ -85,14 +95,24 @@ def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref):
     )[None]
 
 
+def _ffn_kernel_at_layer(layer_ref, *refs):
+    # the layer index is read only by the BlockSpecs' index maps
+    del layer_ref
+    _ffn_kernel(*refs)
+
+
 @functools.partial(
     jax.jit, static_argnames=("block_c", "block_f", "interpret")
 )
 def moe_ffn_pallas(
-    x_e, w_gate, w_up, w_down, *, block_c: int = 128, block_f: int = 256,
-    interpret: bool = False,
+    x_e, w_gate, w_up, w_down, layer=None, *, block_c: int = 128,
+    block_f: int = 256, interpret: bool = False,
 ):
     """x_e (E, C, D), w_gate/w_up (E, D, F), w_down (E, F, D) → (E, C, D).
+
+    With ``layer`` (an int32 scalar, traced or not) the weights carry a
+    leading layer dim, (L, E, D, F) / (L, E, F, D), and layer ``layer``
+    is read in place.
 
     C must divide by ``block_c`` and F by ``block_f`` (the dispatch pads
     capacity to the tile size — that padding IS the latency staircase).
@@ -109,25 +129,52 @@ def moe_ffn_pallas(
             f"C={C} must divide block_c={block_c}, F={F} block_f={block_f}"
         )
     grid = (E, C // block_c, F // block_f)
-    out = pl.pallas_call(
-        _ffn_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_c, D), lambda e, c, f: (e, c, 0)),
-            pl.BlockSpec((1, D, block_f), lambda e, c, f: (e, 0, f)),
-            pl.BlockSpec((1, D, block_f), lambda e, c, f: (e, 0, f)),
-            pl.BlockSpec((1, block_f, D), lambda e, c, f: (e, f, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_c, D), lambda e, c, f: (e, c, 0)),
-        out_shape=jax.ShapeDtypeStruct((E, C, D), jnp.float32),
-        compiler_params=pallas_compiler_params(
-            ("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=max(
-                vmem_bytes(block_c, block_f, D, x_e.dtype.itemsize)
-                + _VMEM_HEADROOM,
-                _VMEM_FLOOR,
-            ),
+    compiler_params = pallas_compiler_params(
+        ("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=max(
+            vmem_bytes(block_c, block_f, D, x_e.dtype.itemsize)
+            + _VMEM_HEADROOM,
+            _VMEM_FLOOR,
         ),
+    )
+    out_shape = jax.ShapeDtypeStruct((E, C, D), jnp.float32)
+    if layer is None:
+        out = pl.pallas_call(
+            _ffn_kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_c, D), lambda e, c, f: (e, c, 0)),
+                pl.BlockSpec((1, D, block_f), lambda e, c, f: (e, 0, f)),
+                pl.BlockSpec((1, D, block_f), lambda e, c, f: (e, 0, f)),
+                pl.BlockSpec((1, block_f, D), lambda e, c, f: (e, f, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_c, D), lambda e, c, f: (e, c, 0)),
+            out_shape=out_shape,
+            compiler_params=compiler_params,
+            interpret=interpret,
+        )(x_e, w_gate, w_up, w_down)
+        return out.astype(x_e.dtype)
+    # the layer dim is squeezed out of the weight blocks, so the kernel
+    # body sees the same (1, D, block_f) / (1, block_f, D) blocks
+    out = pl.pallas_call(
+        _ffn_kernel_at_layer,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_c, D), lambda e, c, f, l: (e, c, 0)),
+                pl.BlockSpec((None, 1, D, block_f),
+                             lambda e, c, f, l: (l[0], e, 0, f)),
+                pl.BlockSpec((None, 1, D, block_f),
+                             lambda e, c, f, l: (l[0], e, 0, f)),
+                pl.BlockSpec((None, 1, block_f, D),
+                             lambda e, c, f, l: (l[0], e, f, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_c, D),
+                                   lambda e, c, f, l: (e, c, 0)),
+        ),
+        out_shape=out_shape,
+        compiler_params=compiler_params,
         interpret=interpret,
-    )(x_e, w_gate, w_up, w_down)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), x_e, w_gate, w_up, w_down)
     return out.astype(x_e.dtype)

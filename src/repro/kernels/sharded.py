@@ -65,7 +65,7 @@ def _shard_map(f, mesh, in_specs, out_specs):
 def moe_ffn_sharded(
     x_e, w_gate, w_up, w_down, *, mesh, data_spec, expert_spec,
     block_c: int = 128, block_f: int = 256, interpret: bool = False,
-    pad_expert_to: int | None = None,
+    pad_expert_to: int | None = None, layer=None,
 ):
     """(Gd, E_v, C, D) expert buffers → (Gd, E_v, C, D) FFN outputs.
 
@@ -89,6 +89,13 @@ def moe_ffn_sharded(
     A 5-D ``x_e`` carries a stacked leading layer dim: (L, Gd, E_v, C, D)
     buffers with (L, E_v, D, F) weights scan the per-layer call over L —
     the whole-stack entry the scan-fused decode executable composes with.
+
+    With ``layer`` (an int32 scalar) the weights are the whole stack,
+    (L, E_v, D, F) / (L, E_v, F, D), and the kernel reads that layer's in
+    place (:func:`~repro.kernels.moe_gemm.moe_ffn_pallas`): a layer scan
+    passes its stacked weights without slicing one layer's out. Where the
+    weights need padding, only that layer is sliced out and padded. This
+    entry is forward only (the decode step's): it has no VJP.
     """
     if x_e.ndim == 5:
         def layer_call(_, xs):
@@ -103,6 +110,12 @@ def moe_ffn_sharded(
     Gd, Ev, C, D = x_e.shape
     F = w_gate.shape[-1]
     Ev_real = Ev
+    bf = min(block_f, _round_up(F, 128))
+    Fp = _round_up(F, bf)
+    if layer is not None and (Fp != F or (pad_expert_to or 0) > Ev):
+        # padding the stack would copy every layer's weights
+        w_gate, w_up, w_down = (w[layer] for w in (w_gate, w_up, w_down))
+        layer = None
     if pad_expert_to is not None and pad_expert_to > Ev:
         ep = pad_expert_to - Ev
         x_e = jnp.pad(x_e, ((0, 0), (0, ep), (0, 0), (0, 0)))
@@ -112,8 +125,6 @@ def moe_ffn_sharded(
         Ev = pad_expert_to
     bc = effective_block_c(block_c, C)
     Cp = _round_up(C, bc)
-    bf = min(block_f, _round_up(F, 128))
-    Fp = _round_up(F, bf)
     if Cp != C:
         x_e = jnp.pad(x_e, ((0, 0), (0, 0), (0, Cp - C), (0, 0)))
     if Fp != F:
@@ -121,11 +132,12 @@ def moe_ffn_sharded(
         w_up = jnp.pad(w_up, ((0, 0), (0, 0), (0, Fp - F)))
         w_down = jnp.pad(w_down, ((0, 0), (0, Fp - F), (0, 0)))
 
-    def per_group(xl, wg, wu, wd):
-        # xl (g_local, e_local, Cp, D): static local group count, ≥ 1
+    def per_group(xl, wg, wu, wd, *at):
+        # xl (g_local, e_local, Cp, D): static local group count, ≥ 1;
+        # at: the layer index of stacked weights, or nothing
         y = jnp.stack([
             moe_ffn_pallas(
-                xl[g], wg, wu, wd, block_c=bc, block_f=bf,
+                xl[g], wg, wu, wd, *at, block_c=bc, block_f=bf,
                 interpret=interpret,
             )
             for g in range(xl.shape[0])
@@ -135,13 +147,18 @@ def moe_ffn_sharded(
     if mesh is None:
         kernel_fwd = per_group
     else:
-        w_spec = P(expert_spec, None, None)
+        stacked = layer is not None
+        w_spec = P(*(None,) * stacked, expert_spec, None, None)
         kernel_fwd = _shard_map(
             per_group, mesh,
             in_specs=(P(data_spec, expert_spec, None, None),
-                      w_spec, w_spec, P(expert_spec, None, None)),
+                      w_spec, w_spec, w_spec, *(P(),) * stacked),
             out_specs=P(data_spec, expert_spec, None, None),
         )
+
+    if layer is not None:
+        y = kernel_fwd(x_e, w_gate, w_up, w_down, layer)
+        return y[:, :Ev_real, :C, :]
 
     @jax.custom_vjp
     def call(xp, wg, wu, wd):

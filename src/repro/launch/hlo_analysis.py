@@ -22,7 +22,8 @@ from __future__ import annotations
 import re
 
 __all__ = [
-    "collective_stats", "arrays_shaped", "expert_weight_shapes", "COLLECTIVES",
+    "collective_stats", "arrays_shaped", "aliased_parameters",
+    "expert_weight_shapes", "COLLECTIVES",
 ]
 
 COLLECTIVES = (
@@ -46,6 +47,12 @@ _PARAM_RE = re.compile(r"parameter\((\d+)\)")
 _CONST_RE = re.compile(r"constant\((\d+)\)")
 _GROUP_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _GROUP_LIST_RE = re.compile(r"replica_groups=\{\{([\d,]+)\}")
+# the opcode follows the result type: the first word that opens a paren
+# after whitespace (layouts such as ``{1,0:T(8,128)}`` open theirs after
+# a colon or a paren)
+_OPCODE_RE = re.compile(r"\s([a-z][\w-]*)\(")
+# ``input_output_alias={ {1}: (12, {}, may-alias), ... }``: parameter numbers
+_ALIAS_RE = re.compile(r"\{[\d,\s]*\}:\s*\((\d+),")
 _RESULT_RE = re.compile(
     r"=\s*(?:\(([^)]*)\)|(\w+)\[([\d,]*)\])\S*\s+([\w-]+?)(?:-start)?\("
 )
@@ -404,24 +411,45 @@ def collective_stats(text: str, *, default_trip: int = 1) -> dict:
     }
 
 
-def arrays_shaped(text: str, dtype: str, shapes) -> list[str]:
+def arrays_shaped(text: str, dtype: str, shapes, opcodes=None) -> list[str]:
     """Instructions of ``text``, fusion bodies included, whose result is a
     ``dtype`` array ending in one of ``shapes`` (leading dims, such as a
-    stacked layer axis, are ignored): e.g. an f32 copy of a bf16 weight."""
+    stacked layer axis, are ignored): e.g. an f32 copy of a bf16 weight.
+    A tuple result (``copy-start``'s) counts when one of its elements
+    does; ``opcodes``, if given, keeps only instructions of those opcodes.
+    """
     want = {tuple(s) for s in shapes}
     hits = []
     for line in text.splitlines():
         m = _DEF_RE.match(line)
         if m is None:
             continue
-        r = re.match(r"(\w+)\[([\d,]*)\]", m.group(2))
-        if r is None or r.group(1) != dtype:
+        op = _OPCODE_RE.search(m.group(2))
+        if op is None or (opcodes is not None and op.group(1) not in opcodes):
             continue
-        dims = tuple(int(d) for d in r.group(2).split(",") if d)
-        if any(dims[len(dims) - len(w):] == w for w in want
-               if len(dims) >= len(w)):
-            hits.append(line.strip())
+        for dt, dims in _SHAPE_RE.findall(m.group(2)[:op.start()]):
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            if dt == dtype and any(dims[len(dims) - len(w):] == w
+                                   for w in want if len(dims) >= len(w)):
+                hits.append(line.strip())
+                break
     return hits
+
+
+def aliased_parameters(text: str) -> list[str]:
+    """The entry computation's parameter instructions that the module's
+    ``input_output_alias`` gives an output buffer: the arguments a
+    donating jit writes in place."""
+    header = next((ln for ln in text.splitlines()
+                   if ln.startswith("HloModule")), "")
+    alias = header.partition("input_output_alias=")[2]
+    alias = alias.partition("entry_computation_layout=")[0]
+    aliased = {int(n) for n in _ALIAS_RE.findall(alias)}
+    comps, entry = _split_computations(text)
+    return [
+        line.strip() for line in comps.get(entry, [])
+        if (p := _PARAM_RE.search(line)) and int(p.group(1)) in aliased
+    ]
 
 
 def expert_weight_shapes(config, model_shards: int = 1) -> list[tuple]:

@@ -347,30 +347,36 @@ def attention_decode_paged(
     p,
     k_pool,
     v_pool,
+    layer,
     block_tables,
     cur_len,
     config: ModelConfig,
     policy: ShardingPolicy,
 ):
-    """One decode step against a paged KV pool (one layer's pool).
+    """One decode step of layer ``layer`` against the stacked paged KV pools.
 
-    x (B, 1, D); ``k_pool``/``v_pool`` (N, bs, KV, hd) — the shared block
-    pool; ``block_tables`` (B, n_max) int32 maps each row's logical
-    positions ``[0, n_max·bs)`` onto physical blocks (block 0 is the null
-    block: inactive rows and unallocated tail entries point there);
-    ``cur_len`` (B,) int32 — per-row valid lengths, so ragged batches need
-    no shared-max zero-panel approximation. The new token is written at
-    physical ``(table[cur_len // bs], cur_len % bs)``; rows whose table
-    entry is the null block scatter harmlessly into block 0, which active
-    rows never own and masked scores never read.
+    x (B, 1, D); ``k_pool``/``v_pool`` (L, N, bs, KV·hd) — every layer's
+    shared block pool, with heads and head dim flattened into one
+    lane-dense minor dim (see :func:`~repro.models.model.init_paged_decode_cache`);
+    ``layer`` int32 scalar — which layer's pool to read and write, so the
+    stacked pools are indexed in place and no layer is sliced out;
+    ``block_tables`` (B, n_max) int32 maps each row's logical positions
+    ``[0, n_max·bs)`` onto physical blocks (block 0 is the null block:
+    inactive rows and unallocated tail entries point there); ``cur_len``
+    (B,) int32 — per-row valid lengths, so ragged batches need no
+    shared-max zero-panel approximation. The new token is written at
+    physical ``(layer, table[cur_len // bs], cur_len % bs)``; rows whose
+    table entry is the null block scatter harmlessly into block 0, which
+    active rows never own and masked scores never read.
 
-    Returns (out (B, 1, D), (new_k_pool, new_v_pool)). Sliding-window
-    attention is not supported on the paged path — the engine keeps the
-    dense cache for those archs.
+    Returns (out (B, 1, D), (new_k_pool, new_v_pool)): the stacked pools
+    with this layer's token written. Sliding-window attention is not
+    supported on the paged path — the engine keeps the dense cache for
+    those archs.
     """
     B = x.shape[0]
     H, KV, hd = config.num_heads, config.num_kv_heads, config.head_dim
-    bs = k_pool.shape[-3]
+    bs = k_pool.shape[2]
     n_max = block_tables.shape[-1]
     S_v = n_max * bs  # logical view length
 
@@ -396,8 +402,8 @@ def attention_decode_paged(
     k_new = apply_rope(k_new, cos[:, None], sin[:, None])
 
     # gather each row's logical cache view through its block table
-    k_view = k_pool[block_tables].reshape(B, S_v, KV, hd)
-    v_view = v_pool[block_tables].reshape(B, S_v, KV, hd)
+    k_view = k_pool[layer, block_tables].reshape(B, S_v, KV, hd)
+    v_view = v_pool[layer, block_tables].reshape(B, S_v, KV, hd)
 
     qg = _grouped(q, config)[:, 0]  # (B, KV, G, hd)
     scale = 1.0 / np.sqrt(hd)
@@ -436,6 +442,10 @@ def attention_decode_paged(
         block_tables, (cur_len // bs)[:, None], axis=1
     )[:, 0]  # (B,) physical block per row
     off = cur_len % bs
-    new_k_pool = k_pool.at[blk, off].set(k_new[:, 0].astype(k_pool.dtype))
-    new_v_pool = v_pool.at[blk, off].set(v_new[:, 0].astype(v_pool.dtype))
+    new_k_pool = k_pool.at[layer, blk, off].set(
+        k_new[:, 0].reshape(B, KV * hd).astype(k_pool.dtype)
+    )
+    new_v_pool = v_pool.at[layer, blk, off].set(
+        v_new[:, 0].reshape(B, KV * hd).astype(v_pool.dtype)
+    )
     return y, (new_k_pool, new_v_pool)

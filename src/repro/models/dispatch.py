@@ -534,12 +534,17 @@ def expert_compute(
     policy: ShardingPolicy,
     *,
     backend: str,
+    layer=None,
 ):
     """Gather per-plan into (Gd, E_v, C, D) buffers, FFN, apply gates.
 
     The gather stays outside any shard_map (its indices cross shards); only
     the FFN itself runs per-device under ``backend="pallas"``. Returns the
     gate-weighted (Gd, E_v, C, D) expert outputs for :func:`combine`.
+
+    With ``layer`` (``backend="pallas"`` only) the expert weights of
+    ``p`` are the whole layer stack, and the kernel reads that layer's in
+    place.
     """
     Gd, Ng, D = xg.shape
     Ev = plan.num_slots  # physical slots: E_v, or more under replication
@@ -575,7 +580,7 @@ def expert_compute(
             mesh=policy.mesh, data_spec=data_spec,
             expert_spec=kernel_expert_spec,
             block_c=config.pallas_block_c, block_f=config.pallas_block_f,
-            interpret=auto_interpret(), pad_expert_to=pad_to,
+            interpret=auto_interpret(), pad_expert_to=pad_to, layer=layer,
         )
     else:
         w_gate, w_up, w_down = p["w_gate"], p["w_up"], p["w_down"]

@@ -420,20 +420,26 @@ def init_paged_decode_cache(config: ModelConfig, num_blocks: int,
                             dtype=jnp.bfloat16):
     """Paged KV pools for ``decode_step(..., block_tables=...)``.
 
-    Shape ``(L, N, block_size, KV, hd)`` per K/V: a shared block pool per
+    Shape ``(L, N, block_size, KV·hd)`` per K/V: a shared block pool per
     layer instead of per-slot ``max_len`` panels — logical sequences map
     onto blocks through the per-request tables managed by
-    :class:`repro.serving.kv_cache.PagedKVPool`. Attention-family archs
-    without a sliding window only (SSM/hybrid state is O(1) per slot and
-    needs no paging; SWA's ring-buffer ages don't survive the block
-    indirection).
+    :class:`repro.serving.kv_cache.PagedKVPool`. Heads and head dim share
+    one minor dim so that it is lane-dense: with ``KV·hd`` a multiple of
+    128 the TPU's default layout is row-major and unpadded, whereas a
+    minor ``hd`` of 64 fills half a 128-lane tile and the compiler makes
+    the block dim minor instead, relayout-copying a layer's pool around
+    every per-token write. ``decode_step`` carries both stacked pools
+    through its layer scan and indexes them in place. Attention-family
+    archs without a sliding window only (SSM/hybrid state is O(1) per
+    slot and needs no paging; SWA's ring-buffer ages don't survive the
+    block indirection).
     """
     if config.is_ssm or config.is_hybrid:
         raise ValueError("paged KV cache requires an attention-family arch")
     if config.sliding_window > 0:
         raise ValueError("paged KV cache does not support sliding windows")
     L = config.num_layers
-    shape = (L, num_blocks, block_size, config.num_kv_heads, config.head_dim)
+    shape = (L, num_blocks, block_size, config.num_kv_heads * config.head_dim)
     # pools are deliberately unconstrained (replicated on a mesh): the
     # block dim is neither a batch nor a sequence axis, so the dense
     # layout's kv_cache spec does not apply
@@ -464,14 +470,15 @@ def decode_step(params, caches, cur_len, tokens, config: ModelConfig,
     Paged mode: ``block_tables`` (B, n_max) int32 and ``cur_len`` (B,)
     int32 route each row's cache traffic through its own block table
     (see :func:`init_paged_decode_cache`) — ragged batches attend at
-    their true lengths. Returns (logits (B, V), new caches, moe aux or
-    None).
+    their true lengths; the stacked pools ride in the layer scan's carry
+    and each layer writes its token into them in place. Returns (logits
+    (B, V), new caches, moe aux or None).
 
     ``decode_mode`` picks the layer-stack lowering contract
     (:func:`_scan_or_unroll`): ``"scan"`` compiles the whole MoE decode
     step as **one** ``lax.scan`` executable whose per-layer router
-    tables, replica tables, slot layouts (``placements``) and caches
-    are scanned operands — any placement or mid-run migration reuses
+    tables, replica tables, slot layouts (``placements``) and dense
+    caches are scanned operands — any placement or mid-run migration reuses
     the same compiled program; ``"python"`` unrolls the identical body
     per layer, the baseline the scan≡python token-parity gates diff
     against.
@@ -561,31 +568,20 @@ def decode_step(params, caches, cur_len, tokens, config: ModelConfig,
         if placements is None:
             placements = identity_placement(config, config.num_layers)
 
-        def layer_body(xc, lp, placement_l, cache, shed_l):
-            # named scopes split a profile of the compiled step by layer
-            # part; what is left directly under "layer_scan" is the scan's
-            # own operand and carry handling
-            with jax.named_scope("attention"):
-                h = rms_norm(xc, lp["ln1"], config.norm_eps)
-                if block_tables is not None:
-                    a, (new_k, new_v) = attention_decode_paged(
-                        h, lp["attn"], cache["k"], cache["v"], block_tables,
-                        cur_len, config, policy,
-                    )
-                    new_c = AttnCache(new_k, new_v)
-                else:
-                    a, new_c = attention_decode(
-                        h, lp["attn"], AttnCache(cache["k"], cache["v"]),
-                        cur_len, config, policy,
-                    )
-                xc = xc + a
+        # named scopes split a profile of the compiled step by layer part;
+        # what is left directly under "layer_scan" is the scan's own
+        # operand and carry handling
+        def ffn_half(xc, lp, placement_l, shed_l, layer=None, experts=None):
+            # experts: the stacked expert weights, read at ``layer`` in
+            # place; None when ``lp`` holds this layer's own
             with jax.named_scope("ffn"):
                 h2 = rms_norm(xc, lp["ln2"], config.norm_eps)
                 if config.is_moe:
                     y, aux = moe_layer(
-                        h2, lp["moe"], placement_l, config, policy,
+                        h2, {**lp["moe"], **(experts or {})}, placement_l,
+                        config, policy,
                         capacity_factor=config.decode_capacity_factor,
-                        shed_enable=shed_l,
+                        shed_enable=shed_l, layer=layer,
                     )
                 else:
                     aux = _moe_aux_zero(config) if config.is_moe else 0.0
@@ -596,26 +592,66 @@ def decode_step(params, caches, cur_len, tokens, config: ModelConfig,
                 if config.is_moe and aux is None:
                     aux = _moe_aux_zero(config)
                 xc = xc + y
-            return xc, ({"k": new_c.k, "v": new_c.v}, aux)
+            return xc, aux
 
-        if shed_enables is None:
-            # pre-shed operand tuple: the traced program (and therefore
-            # every existing compiled decode executable) is unchanged
-            def body(xc, inputs):
-                lp, placement_l, cache = inputs
-                return layer_body(xc, lp, placement_l, cache, None)
+        # shed_enables=None is an empty pytree node: the scanned operands
+        # (and therefore every compiled decode executable) are then the
+        # pre-shed ones
+        layer_ops = (blocks, placements, shed_enables)
 
-            xs = (blocks, placements, caches["attn"])
+        if block_tables is not None:
+            # the stacked pools ride in the carry and each layer reads and
+            # writes its own in place through the scanned layer index: no
+            # layer's pool is sliced out of the stack or written back. The
+            # expert weights stay stacked as well, closed over by the body,
+            # and the expert kernel reads each layer's in place
+            experts = None
+            if config.is_moe:
+                moe_p = dict(blocks["moe"])
+                experts = {n: moe_p.pop(n)
+                           for n in ("w_gate", "w_up", "w_down")}
+                layer_ops = ({**blocks, "moe": moe_p}, placements,
+                             shed_enables)
+
+            def body(carry, inputs):
+                xc, k_pool, v_pool = carry
+                (lp, placement_l, shed_l), layer = inputs
+                with jax.named_scope("attention"):
+                    h = rms_norm(xc, lp["ln1"], config.norm_eps)
+                    a, (k_pool, v_pool) = attention_decode_paged(
+                        h, lp["attn"], k_pool, v_pool, layer, block_tables,
+                        cur_len, config, policy,
+                    )
+                    xc = xc + a
+                xc, aux = ffn_half(xc, lp, placement_l, shed_l, layer, experts)
+                return (xc, k_pool, v_pool), aux
+
+            carry = (x, caches["attn"]["k"], caches["attn"]["v"])
+            xs = (layer_ops, jnp.arange(config.num_layers, dtype=jnp.int32))
+            with jax.named_scope("layer_scan"):
+                (x, k_pool, v_pool), auxes = _scan_or_unroll(
+                    body, carry, xs, decode_mode
+                )
+            new_caches = {"attn": {"k": k_pool, "v": v_pool}}
         else:
             def body(xc, inputs):
-                lp, placement_l, shed_l, cache = inputs
-                return layer_body(xc, lp, placement_l, cache, shed_l)
+                (lp, placement_l, shed_l), cache = inputs
+                with jax.named_scope("attention"):
+                    h = rms_norm(xc, lp["ln1"], config.norm_eps)
+                    a, new_c = attention_decode(
+                        h, lp["attn"], AttnCache(cache["k"], cache["v"]),
+                        cur_len, config, policy,
+                    )
+                    xc = xc + a
+                xc, aux = ffn_half(xc, lp, placement_l, shed_l)
+                return xc, ({"k": new_c.k, "v": new_c.v}, aux)
 
-            xs = (blocks, placements, shed_enables, caches["attn"])
-
-        with jax.named_scope("layer_scan"):
-            x, (new_attn, auxes) = _scan_or_unroll(body, x, xs, decode_mode)
-        new_caches = {"attn": new_attn}
+            xs = (layer_ops, caches["attn"])
+            with jax.named_scope("layer_scan"):
+                x, (new_attn, auxes) = _scan_or_unroll(
+                    body, x, xs, decode_mode
+                )
+            new_caches = {"attn": new_attn}
         if config.is_moe:
             moe_aux = auxes
 

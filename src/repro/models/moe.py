@@ -224,6 +224,7 @@ def moe_layer(
     seq_sharded_out: bool = False,
     backend: str | None = None,
     shed_enable=None,
+    layer=None,
 ):
     """x (B, S, D) replicated over model → (y (B,S,D), :class:`MoEAux`).
 
@@ -248,8 +249,17 @@ def moe_layer(
     weight pool ``p`` whose expert rows carry the replica copies — the
     physical slot count is read off the stacked weights, so the same layer
     code serves single-copy and replicated pools.
+
+    ``layer`` (int32 scalar, or None): ``p``'s expert weights are then the
+    whole (L, …) stack and this is layer ``layer``; the Pallas backend
+    reads its weights in place, so a layer scan need not slice them out.
     """
     backend = resolve_moe_backend(backend, config, policy)
+    num_slots = int(p["w_gate"].shape[-3])
+    if layer is not None and backend != "pallas":
+        # only the kernel reads a layer of the stack in place
+        p = {**p, **{n: p[n][layer] for n in ("w_gate", "w_up", "w_down")}}
+        layer = None
     B, S, D = x.shape
     # `is None`, not falsy-or: an explicit 0.0 means "minimum capacity"
     cf = (
@@ -289,14 +299,15 @@ def moe_layer(
             dropped_tokens=jnp.asarray(0, jnp.int32),
             overflow_tokens=jnp.asarray(0, jnp.int32),
             shed_tokens=jnp.asarray(0, jnp.int32),
-            shed_delta=jnp.zeros((int(p["w_gate"].shape[0]),), jnp.int32),
+            shed_delta=jnp.zeros((num_slots,), jnp.int32),
         )
 
     plan = build_dispatch(
         router, expert_to_slot, config, policy, capacity_factor=cf,
-        num_slots=int(p["w_gate"].shape[0]), shed_enable=shed_enable,
+        num_slots=num_slots, shed_enable=shed_enable,
     )
-    y_e = expert_compute(xg, plan, p, config, policy, backend=backend)
+    y_e = expert_compute(xg, plan, p, config, policy, backend=backend,
+                         layer=layer)
     y = combine(y_e, plan, (B, S, D), policy, seq_sharded_out=seq_sharded_out)
     return y, MoEAux(
         expert_counts=router.expert_counts,

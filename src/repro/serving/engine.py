@@ -193,6 +193,13 @@ class EngineConfig:
     hbm_budget_bytes: float | None = None
 
 
+def _donate_caches() -> bool:
+    """Whether the paged decode and install donate the KV pools they are
+    given: on accelerators. On the CPU backend callers may keep a step's
+    input cache after the step, which a donated buffer would not survive."""
+    return jax.default_backend() != "cpu"
+
+
 class ServingEngine:
     def __init__(
         self,
@@ -539,21 +546,28 @@ class ServingEngine:
                     shed_enables=shed,
                 )
 
-            self._decode = jax.jit(_decode_paged)
-            KV, hd = config.num_kv_heads, config.head_dim
+            # the pools are donated, so each step writes its tokens into
+            # the buffers it was given
+            donate = _donate_caches()
+            self._decode = jax.jit(
+                _decode_paged, donate_argnums=(1,) if donate else ()
+            )
 
             def _install(pool, new, blocks):
-                # new (L, 1, P, KV, hd): pad P up to n·bs, reshape to
+                # new (L, 1, P, KV, hd): flatten the heads into the pool's
+                # lane-dense KV·hd dim, pad P up to n·bs, reshape to
                 # blocks, scatter into the pool rows this request owns
                 L, _, P = new.shape[:3]
                 n = blocks.shape[0]
                 newp = jnp.pad(
-                    new[:, 0],
-                    ((0, 0), (0, n * block_size - P), (0, 0), (0, 0)),
-                ).reshape(L, n, block_size, KV, hd)
+                    new[:, 0].reshape(L, P, pool.shape[-1]),
+                    ((0, 0), (0, n * block_size - P), (0, 0)),
+                ).reshape(L, n, block_size, pool.shape[-1])
                 return pool.at[:, blocks].set(newp)
 
-            self._paged_install = jax.jit(_install)
+            self._paged_install = jax.jit(
+                _install, donate_argnums=(0,) if donate else ()
+            )
         else:
             self.caches = init_decode_cache(
                 config, engine_config.max_batch, engine_config.max_len,

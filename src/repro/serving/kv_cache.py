@@ -7,7 +7,12 @@ prompt/output lengths, so the physical cache bounded concurrency at
 replaces that layout with vLLM-style paging:
 
   * the physical cache is a pool of ``num_blocks`` fixed-size blocks per
-    layer, shaped ``(L, N, block_size, KV, hd)``;
+    layer, shaped ``(L, N, block_size, KV·hd)``: heads and head dim share
+    one lane-dense minor dim, a multiple of 128 at every paged arch's
+    published width, so the TPU keeps the pool row-major and unpadded and
+    the decode step writes each token into it in place (a minor ``hd`` of
+    64 would fill half a 128-lane tile, and the compiler would make the
+    block dim minor and relayout-copy a layer's pool around every write);
   * each live request owns an ordered *block table* — the logical sequence
     ``[0, cur_len)`` maps to ``table[pos // block_size][pos % block_size]``;
   * blocks come from a free list; allocation is all-or-nothing, release

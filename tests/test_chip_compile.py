@@ -22,7 +22,11 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.kernels.moe_gemm import SKINNY_BLOCK_C, moe_ffn_pallas
 from repro.kernels.topk_router import topk_router_pallas
-from repro.launch.hlo_analysis import arrays_shaped, expert_weight_shapes
+from repro.launch.hlo_analysis import (
+    aliased_parameters,
+    arrays_shaped,
+    expert_weight_shapes,
+)
 from repro.launch.mesh import policy_for
 from repro.launch.specs import abstract_params, cache_specs
 from repro.models.model import decode_step, init_paged_decode_cache
@@ -102,15 +106,26 @@ def test_topk_router_compiles_for_v5e(one_chip, T, with_stats):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# the serving cell's paged pool: 32 slots of 1296 tokens in 16-token
+# blocks, 81 table entries a slot, and the null block
+CELL_SLOTS, CELL_BLOCK, CELL_N_MAX = 32, 16, 1296 // 16
+CELL_BLOCKS = 1 + CELL_SLOTS * CELL_N_MAX
+# one layer's K or V pool, lane-dense: (N, block_size, KV·hd)
+POOL_LAYER = (CELL_BLOCKS, CELL_BLOCK, GRANITE.num_kv_heads * GRANITE.head_dim)
+# instructions that move a pool or one layer of it rather than write a token
+POOL_MOVES = ("copy", "copy-start", "copy-done", "dynamic-slice",
+              "dynamic-update-slice")
+
+
 def _granite_decode(backend: str, sharding):
     """The full-width bf16 granite decode step (scan mode) compiled for
-    ``sharding``: one described chip with 8 slots over a 2048-token paged
-    pool, or a (1, 4) mesh of them with a dense 640-token cache (the paged
-    pool does not shard)."""
+    ``sharding``: one described chip with the serving cell's paged pool,
+    donated as the engine donates it, or a (1, 4) mesh of them with 8
+    slots over a dense 640-token cache (the paged pool does not shard)."""
     cfg = dataclasses.replace(GRANITE, moe_backend=backend)
     Ev = cfg.num_experts * cfg.expert_tp
-    B = 8
     if isinstance(sharding, Mesh):
+        B = 8
         policy = policy_for(sharding, step_kind="decode")
         params, _ = abstract_params(cfg, policy, jnp.bfloat16)
         caches, _ = cache_specs(cfg, policy, B, 640, jnp.bfloat16)
@@ -126,11 +141,10 @@ def _granite_decode(backend: str, sharding):
                 rep((cfg.num_layers, Ev), jnp.int32))
         return jax.jit(step).lower(*args).compile()
     policy = host_policy()
-    block, max_len = 16, 2048
-    n_max = max_len // block
+    B, n_max = CELL_SLOTS, CELL_N_MAX
     params, _ = abstract_params(cfg, policy, jnp.bfloat16)
     caches = jax.eval_shape(lambda: init_paged_decode_cache(
-        cfg, 1 + B * n_max, block, policy, jnp.bfloat16))
+        cfg, CELL_BLOCKS, CELL_BLOCK, policy, jnp.bfloat16))
     args = jax.tree.map(
         lambda s: _shape(sharding, s.shape, s.dtype),
         (params, caches,
@@ -144,7 +158,7 @@ def _granite_decode(backend: str, sharding):
         return decode_step(params, caches, cur_len, tokens, cfg, policy,
                            placements, block_tables=tables)
 
-    return jax.jit(paged_step).lower(*args).compile()
+    return jax.jit(paged_step, donate_argnums=(1,)).lower(*args).compile()
 
 
 def test_granite_decode_step_lowers_real_kernels(one_chip, monkeypatch):
@@ -153,6 +167,80 @@ def test_granite_decode_step_lowers_real_kernels(one_chip, monkeypatch):
     kernels would be lowered interpreted."""
     monkeypatch.setattr("repro.models.dispatch.auto_interpret", lambda: False)
     assert "tpu_custom_call" in _granite_decode("pallas", one_chip).as_text()
+
+
+def test_granite_paged_decode_writes_the_pool_in_place(one_chip, monkeypatch):
+    """The paged decode carries the stacked K/V pools through its layer
+    scan and scatters each token into them: no instruction copies or
+    slices out a layer's pool, and both donated pools alias the step's
+    output pools, so none is copied out and back on a step."""
+    monkeypatch.setattr("repro.models.dispatch.auto_interpret", lambda: False)
+    text = _granite_decode("pallas", one_chip).as_text()
+    assert arrays_shaped(text, "bf16", [POOL_LAYER])  # the pools are found
+    assert arrays_shaped(text, "bf16", [POOL_LAYER], opcodes=POOL_MOVES) == []
+    aliased = aliased_parameters(text)
+    assert len(arrays_shaped("\n".join(aliased), "bf16", [POOL_LAYER])) == 2
+
+
+# instructions that would hold one layer's expert weights taken out of the
+# stack: a slice, a copy, or a fusion that slices
+WEIGHT_MOVES = ("fusion", "dynamic-slice", "slice", "copy", "copy-start",
+                "copy-done")
+
+
+def test_granite_paged_decode_reads_expert_weights_in_place(one_chip,
+                                                            monkeypatch):
+    """The paged decode hands the expert kernel the stacked weights and
+    the layer index: no instruction slices or copies one layer's expert
+    weights out of the stack. Sliced out, they cost a full extra read and
+    write of every expert weight a step, and the compiler may stage one
+    of the slices in VMEM ahead of the kernel."""
+    monkeypatch.setattr("repro.models.dispatch.auto_interpret", lambda: False)
+    text = _granite_decode("pallas", one_chip).as_text()
+    shapes = expert_weight_shapes(GRANITE, 1)
+    assert arrays_shaped(text, "bf16", shapes)  # the stacks are found
+    assert arrays_shaped(text, "bf16", shapes, opcodes=WEIGHT_MOVES) == []
+
+
+def test_arrays_shaped_finds_expert_weights_sliced_out_of_a_scan(one_chip):
+    """The check above can fail: a scan that takes the stacked weights as
+    scanned operands and gives the kernel each layer's own has them
+    sliced out, and that is found."""
+    E, D, F = expert_weight_shapes(GRANITE, 1)[0]
+    bf16 = jnp.bfloat16
+
+    def step(x, wg, wu, wd):
+        def body(carry, w):
+            return carry, moe_ffn_pallas(x, *w, block_c=SKINNY_BLOCK_C,
+                                         block_f=F)
+        return jax.lax.scan(body, 0, (wg, wu, wd))[1]
+
+    text = jax.jit(step).lower(
+        _shape(one_chip, (E, SKINNY_BLOCK_C, D), bf16),
+        _shape(one_chip, (2, E, D, F), bf16),
+        _shape(one_chip, (2, E, D, F), bf16),
+        _shape(one_chip, (2, E, F, D), bf16),
+    ).compile().as_text()
+    assert arrays_shaped(text, "bf16", [(E, D, F)], opcodes=WEIGHT_MOVES)
+
+
+def test_arrays_shaped_finds_a_pool_layer_moved_through_a_scan(one_chip):
+    """The check above can fail: a scan that takes the stacked pool as a
+    scanned operand, writes a token into each layer's slice and stacks the
+    slices back as its output has a layer's pool sliced out and written
+    back, and that is found."""
+    bf16 = jnp.bfloat16
+
+    def step(pool, row):
+        def body(carry, layer):
+            return carry, layer.at[0, 0].set(row)
+        return jax.lax.scan(body, 0, pool)[1]
+
+    text = jax.jit(step).lower(
+        _shape(one_chip, (4, *POOL_LAYER), bf16),
+        _shape(one_chip, POOL_LAYER[-1:], bf16),
+    ).compile().as_text()
+    assert arrays_shaped(text, "bf16", [POOL_LAYER], opcodes=POOL_MOVES)
 
 
 @pytest.mark.parametrize("chips", [1, 4])

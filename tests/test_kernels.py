@@ -5,7 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.moe_gemm import moe_ffn_pallas
 from repro.kernels.ops import moe_ffn, moe_ffn_ref, topk_router, topk_router_ref
+from repro.kernels.sharded import moe_ffn_sharded
 
 FFN_SHAPES = [
     # (E, C, D, F, block_c, block_f)
@@ -32,6 +34,43 @@ def test_moe_ffn_matches_ref(E, C, D, F, bc, bf, dtype):
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         rtol=tol, atol=tol,
     )
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_ffn_reads_a_stacked_layer_in_place(layer, dtype):
+    """Given the whole (L, E, D, F) stack and a traced layer index, the
+    kernel computes exactly what it computes on that layer's own weights."""
+    L, E, C, D, F = 3, 4, 8, 128, 256
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    x = jax.random.normal(ks[0], (E, C, D), dtype)
+    wg = (jax.random.normal(ks[1], (L, E, D, F), dtype) * 0.05).astype(dtype)
+    wu = (jax.random.normal(ks[2], (L, E, D, F), dtype) * 0.05).astype(dtype)
+    wd = (jax.random.normal(ks[3], (L, E, F, D), dtype) * 0.05).astype(dtype)
+    kw = dict(block_c=8, block_f=128, interpret=True)
+    got = jax.jit(lambda l: moe_ffn_pallas(x, wg, wu, wd, l, **kw))(
+        jnp.int32(layer))
+    want = moe_ffn_pallas(x, wg[layer], wu[layer], wd[layer], **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("F", [256, 192])
+def test_moe_ffn_sharded_reads_a_stacked_layer(F):
+    """The per-group entry passes a stacked layer through to the kernel,
+    and where F needs padding (192) takes that one layer out first: both
+    match the call on the layer's own weights."""
+    L, G, E, C, D = 2, 2, 4, 3, 128
+    ks = jax.random.split(jax.random.PRNGKey(F), 4)
+    x = jax.random.normal(ks[0], (G, E, C, D), jnp.float32)
+    wg = jax.random.normal(ks[1], (L, E, D, F), jnp.float32) * 0.05
+    wu = jax.random.normal(ks[2], (L, E, D, F), jnp.float32) * 0.05
+    wd = jax.random.normal(ks[3], (L, E, F, D), jnp.float32) * 0.05
+    kw = dict(mesh=None, data_spec=None, expert_spec=None, block_c=8,
+              block_f=256, interpret=True)
+    got = moe_ffn_sharded(x, wg, wu, wd, layer=jnp.int32(1), **kw)
+    want = moe_ffn_sharded(x, wg[1], wu[1], wd[1], **kw)
+    assert got.shape == want.shape == (G, E, C, D)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_moe_ffn_rejects_unaligned_capacity():

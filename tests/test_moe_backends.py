@@ -205,6 +205,35 @@ def test_pallas_runs_under_mesh():
     )
 
 
+def test_pallas_reads_a_stacked_layer_under_mesh():
+    """Given the whole layer stack and a layer index, moe_layer's
+    shard_map kernel path reads that layer's weights in place and matches
+    the call on the layer's own weights."""
+    from jax.sharding import Mesh
+    from repro.sharding.policy import ShardingPolicy
+
+    cfg = dataclasses.replace(
+        get_smoke_config("mixtral-8x7b"), capacity_factor=8.0,
+        expert_d_ff=128,
+    )
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    policy = ShardingPolicy(mesh=mesh)
+    params, _ = init_moe(
+        jax.random.PRNGKey(0), cfg, num_layers=2, dtype=jnp.float32,
+        policy=policy,
+    )
+    lp = jax.tree.map(lambda t: t[1], params)
+    stacked = {**params, "router": lp["router"]}
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
+    table = identity_placement(cfg, 1)[0]
+    with mesh:
+        y_ref, _ = moe_layer(x, lp, table, cfg, policy, backend="pallas")
+        y, _ = jax.jit(lambda s, l: moe_layer(
+            x, s, table, cfg, policy, backend="pallas", layer=l,
+        ))(stacked, jnp.int32(1))
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_ref))
+
+
 def test_pallas_gradients_match_einsum(setup):
     """The pallas kernels are differentiable (custom_vjp with reference-math
     backward): grads of a scalar loss through moe_layer match einsum."""

@@ -310,6 +310,32 @@ def test_paged_and_dense_engines_generate_identical_tokens():
         assert rp.generated == done_d[uid].generated
 
 
+def test_donated_paged_engine_generates_identical_tokens(monkeypatch):
+    """On the chip the paged engine donates its KV pools to the decode and
+    to the install. Forced on here: a small pool that preempts and
+    reinstalls between decode steps gives the tokens of an undonated
+    engine, and each step really gives its input pools away."""
+    import repro.serving.engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "_donate_caches", lambda: True)
+    kw = dict(max_batch=2, kv=PagedKVConfig(block_size=4, num_blocks=8))
+    donated, cfg = _engine(**kw)
+    monkeypatch.undo()
+    plain, _ = _engine(**kw)
+    prompts = _prompts(cfg, 2, plen=8, seed=3)
+    for eng in (donated, plain):
+        for p in prompts:
+            eng.submit(p, max_new_tokens=12)
+    given = donated.caches["attn"]["k"]
+    donated.step()
+    assert given.is_deleted()
+    done_d = {r.uid: r for r in donated.run(max_steps=300)}
+    done_p = {r.uid: r for r in plain.run(max_steps=300)}
+    assert len(done_d) == 2 and donated.preemption_count > 0
+    for uid, r in done_d.items():
+        assert r.generated == done_p[uid].generated
+
+
 def test_serve_batch_arrivals_matches_submit_run_bit_exact():
     """Trace-replay parity: the all-at-t=0 arrival stream must reproduce
     submit()+run() tokens bit-for-bit."""
