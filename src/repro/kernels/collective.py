@@ -38,9 +38,9 @@ path instead (see :func:`repro.models.moe.apply_layer_permutation`).
 lowered schedule into the traced program, so every applied batch pays a
 fresh jit (~0.3 s) — fine at load time, fatal at decode cadence.
 :class:`MigrationExecutable` is the serving-loop form: one jit traced
-*once* whose (L, S) row-source map is a **traced operand** (a scanned
-operand of an internal ``lax.scan`` over layers). ``ppermute``'s
-permutation must be static, so the operand-driven exchange uses
+*once* whose (L, S) row-source map is a **traced operand**, read by a
+loop over layers that rewrites the donated stacks one layer at a time.
+``ppermute``'s permutation must be static, so the operand-driven exchange uses
 ``lax.all_to_all`` instead — every shard offers each peer the local rows
 that peer's slots want (readable off the traced map), and each receiver
 selects by owner shard; a dense exchange whose *program* is
@@ -277,7 +277,12 @@ class MigrationExecutable:
     per signature (tables present/absent) and **every subsequent
     migration batch — any swap set, any layer subset, mid-run — reuses
     the compiled executable**: zero traces on apply, which the engine's
-    trace counters assert. With ``mesh`` the exchange runs as a
+    trace counters assert. The stacks are rewritten one layer at a time
+    inside that one executable: a layer's rows are gathered, then written
+    over the layer in place, so an apply holds one layer of one weight
+    beside the stacks and never a second stack (the ``migrate`` device
+    scope; the ``migrate.expert_bytes`` counter adds the bytes of the
+    rows that took another slot's weights). With ``mesh`` the exchange runs as a
     ``lax.all_to_all`` under ``shard_map`` over mesh axis ``axis``; with
     ``mesh=None`` it is the jitted host gather. Weight buffers are
     donated (in-place rewrite) except on the CPU backend, where XLA
@@ -303,27 +308,22 @@ class MigrationExecutable:
             def exchange(src, *blks):
                 # blks: this shard's (L, per, …) blocks; src replicated
                 me = jax.lax.axis_index(axis)
+                per = blks[0].shape[1]
 
-                def body(_, xs):
-                    src_l, blk_l = xs[0], xs[1:]
-                    per = blk_l[0].shape[0]
-                    wants = src_l.reshape(n, per)  # rows each shard needs
+                def new_layer(l, b):
+                    wants = src[l].reshape(n, per)  # rows each shard needs
                     owner = wants // per
                     loc = wants % per
                     own_me = jax.lax.dynamic_index_in_dim(
                         owner, me, 0, keepdims=False)
-                    new_l = []
-                    for b in blk_l:
-                        # offer every peer the local rows its slots want
-                        # (identity rows ride along; XLA owns the wire),
-                        # then keep what this shard's true owners sent
-                        outgoing = b[loc]  # (n, per, …)
-                        recv = jax.lax.all_to_all(outgoing, axis, 0, 0)
-                        new_l.append(recv[own_me, jnp.arange(per)])
-                    return None, tuple(new_l)
+                    # offer every peer the local rows its slots want
+                    # (identity rows ride along; XLA owns the wire), then
+                    # keep what this shard's true owners sent
+                    outgoing = b[l][loc]  # (n, per, …)
+                    recv = jax.lax.all_to_all(outgoing, axis, 0, 0)
+                    return recv[own_me, jnp.arange(per)]
 
-                _, new = jax.lax.scan(body, None, (src, *blks))
-                return new
+                return _rewrite_by_layer(blks, new_layer)
 
             def fn(src, tables, *ws):
                 self._count_trace()
@@ -336,7 +336,8 @@ class MigrationExecutable:
                     in_specs=(P(None, None),) + wspecs,
                     out_specs=wspecs,
                 )
-                new_ws = mapped(src, *ws)
+                with jax.named_scope("migrate"):
+                    new_ws = mapped(src, *ws)
                 new_tables = (None if tables is None
                               else _swap_tables(tables, src))
                 return new_ws, new_tables
@@ -352,14 +353,53 @@ class MigrationExecutable:
 
     def _host_apply(self, src, tables, *ws):
         self._count_trace()
-        gather = jax.vmap(lambda a, s: jnp.take(a, s, axis=0))
-        new_ws = tuple(gather(w, src) for w in ws)
+
+        def new_layer(l, w):
+            # row s of the layer ← row src[l, s] of the same layer, copied
+            # row by row into one layer's buffer. A one-op gather of the
+            # layer's rows is no faster, and for v5e XLA gives it ≈ 2.4
+            # layers of temporaries at mixtral's widths and a copy of the
+            # whole stack at granite's
+            def row(s, buf):
+                start = (l, src[l, s]) + (0,) * (w.ndim - 2)
+                r = jax.lax.dynamic_slice(w, start, (1, 1) + w.shape[2:])
+                return jax.lax.dynamic_update_slice_in_dim(buf, r[0], s, 0)
+
+            return jax.lax.fori_loop(0, w.shape[1], row,
+                                     jnp.zeros(w.shape[1:], w.dtype))
+
+        with jax.named_scope("migrate"):
+            new_ws = _rewrite_by_layer(ws, new_layer)
         new_tables = None if tables is None else _swap_tables(tables, src)
         return new_ws, new_tables
 
     def __call__(self, src, tables, w_gate, w_up, w_down):
+        if self.telemetry is not None:
+            # the rows whose source is another slot, in all three stacks
+            host = np.asarray(src)
+            moved = np.count_nonzero(host != np.arange(host.shape[1]))
+            self.telemetry.counter("migrate.expert_bytes").inc(
+                moved * sum(w.nbytes for w in (w_gate, w_up, w_down))
+                // host.size)
         src = jnp.asarray(src, jnp.int32)
         return self._apply(src, tables, w_gate, w_up, w_down)
+
+
+def _rewrite_by_layer(ws, new_layer):
+    """Rewrite the stacked ``(L, S, …)`` arrays ``ws`` one layer at a
+    time: layer ``l`` of each array ``w`` becomes ``new_layer(l, w)``,
+    which reads only layer ``l`` of ``w``, not yet rewritten. One loop
+    per array carries its (donated) stack and updates each layer in
+    place, and the loops run one after another, so the only temporary
+    is one layer of one array, never a second stack."""
+    def rewrite(w):
+        def body(l, w):
+            return jax.lax.dynamic_update_index_in_dim(
+                w, new_layer(l, w), l, 0)
+
+        return jax.lax.fori_loop(0, w.shape[0], body, w)
+
+    return tuple(rewrite(w) for w in ws)
 
 
 def broadcast_expert_row(arrays, src_slot: int, dst_slots, *, mesh,

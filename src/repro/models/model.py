@@ -163,9 +163,21 @@ def _scan_or_unroll(f, init, xs, mode: str):
 # Blocks (train / prefill path: residual sequence-sharded)
 # ---------------------------------------------------------------------------
 
+def _split_stacked_experts(blocks):
+    """``blocks`` without the stacked expert weights, and those weights.
+    A layer scan closes over the weights and hands them to the expert
+    kernel whole with the scanned layer index, so no layer's are sliced
+    out of the stack."""
+    moe_p = dict(blocks["moe"])
+    experts = {n: moe_p.pop(n) for n in ("w_gate", "w_up", "w_down")}
+    return {**blocks, "moe": moe_p}, experts
+
+
 def _attn_block_train(x, lp, placement_l, config: ModelConfig,
                       policy: ShardingPolicy, *, return_cache: bool,
-                      capacity_factor=None):
+                      capacity_factor=None, layer=None, experts=None):
+    # experts: the stacked expert weights, read at ``layer`` in place;
+    # None when ``lp`` holds this layer's own
     with jax.named_scope("attention"):
         h = rms_norm(x, lp["ln1"], config.norm_eps)
         a, cache = attention_train(
@@ -180,8 +192,9 @@ def _attn_block_train(x, lp, placement_l, config: ModelConfig,
         if config.is_moe:
             h2 = policy.act_bsd(h2)  # gather tokens across the model axis
             y, aux = moe_layer(
-                h2, lp["moe"], placement_l, config, policy,
-                capacity_factor=capacity_factor, seq_sharded_out=True,
+                h2, {**lp["moe"], **(experts or {})}, placement_l, config,
+                policy, capacity_factor=capacity_factor,
+                seq_sharded_out=True, layer=layer,
             )
         else:
             h2 = policy.act_bsd(h2)
@@ -300,22 +313,30 @@ def _stack_forward(x, params, placements, config: ModelConfig,
         return x, ({"ssm": caches} if return_cache else None), None
 
     # attention families
+    if placements is None:
+        placements = identity_placement(config, config.num_layers)
+    experts, layers = None, None
+    if config.is_moe and return_cache:
+        # prefill, forward only, reads the expert stacks in place.
+        # Training keeps them scanned: the in-place read has no VJP
+        blocks, experts = _split_stacked_experts(blocks)
+        layers = jnp.arange(config.num_layers, dtype=jnp.int32)
+
     def body(xc, inputs):
-        lp, placement_l = inputs
+        (lp, placement_l), layer = inputs
         xc, cache, aux = _attn_block_train(
             xc, lp, placement_l, config, policy,
             return_cache=return_cache, capacity_factor=capacity_factor,
+            layer=layer, experts=experts,
         )
         if aux is None:
             aux = _moe_aux_zero(config) if config.is_moe else 0.0
         return xc, (cache, aux)
     if remat:
         body = jax.checkpoint(body)
-    if placements is None:
-        placements = identity_placement(config, config.num_layers)
     with jax.named_scope("layer_scan"):
         x, (caches, auxes) = _scan_or_unroll(
-            body, x, (blocks, placements), stack_mode
+            body, x, ((blocks, placements), layers), stack_mode
         )
     moe_aux = auxes if config.is_moe else None
     return x, ({"attn": caches} if return_cache else None), moe_aux
@@ -607,11 +628,8 @@ def decode_step(params, caches, cur_len, tokens, config: ModelConfig,
             # and the expert kernel reads each layer's in place
             experts = None
             if config.is_moe:
-                moe_p = dict(blocks["moe"])
-                experts = {n: moe_p.pop(n)
-                           for n in ("w_gate", "w_up", "w_down")}
-                layer_ops = ({**blocks, "moe": moe_p}, placements,
-                             shed_enables)
+                moe_blocks, experts = _split_stacked_experts(blocks)
+                layer_ops = (moe_blocks, placements, shed_enables)
 
             def body(carry, inputs):
                 xc, k_pool, v_pool = carry

@@ -11,6 +11,7 @@ one process may load the TPU library at a time, and under pytest-xdist every
 worker imports this file.
 """
 import dataclasses
+import functools
 import os
 
 import jax
@@ -274,3 +275,141 @@ def test_arrays_shaped_finds_a_materialized_upcast(one_chip):
         _shape(one_chip, (E, 4, D), bf16), _shape(one_chip, (E, D, F), bf16)
     ).compile().as_text()
     assert arrays_shaped(text, "f32", [(E, D, F)])
+
+
+# the mixtral-8x7b-4l benchmark configuration: published widths, four
+# whole layers, no window (none binds at the cell's 1296 positions), the
+# Pallas backend and dropless capacity factors of E/k
+MIXTRAL_4L = dataclasses.replace(
+    MIXTRAL, num_layers=4, sliding_window=0, moe_backend="pallas",
+    capacity_factor=4.0, decode_capacity_factor=4.0)
+# one v5e chip's memory
+CHIP_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def mixtral_programs(one_chip):
+    """The 4-layer mixtral prefill, paged decode and migration apply,
+    compiled once for the module with the Mosaic kernels lowered."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr("repro.models.dispatch.auto_interpret", lambda: False)
+        return {w: _mixtral_program(one_chip, w)
+                for w in ("prefill", "decode", "migrate")}
+
+
+def _mixtral_program(one_chip, which):
+    """A 4-layer mixtral program compiled for one described chip, with the
+    weights (and the paged pool where it is an argument) as arguments.
+    Returns (compiled, bytes of the cell's arguments it does not take)."""
+    from repro.kernels.collective import MigrationExecutable
+    from repro.models.model import prefill
+
+    cfg, policy = MIXTRAL_4L, host_policy()
+    Ev = cfg.num_experts * cfg.expert_tp
+    params, _ = abstract_params(cfg, policy, jnp.bfloat16)
+    caches = jax.eval_shape(lambda: init_paged_decode_cache(
+        cfg, CELL_BLOCKS, CELL_BLOCK, policy, jnp.bfloat16))
+    on_chip = functools.partial(jax.tree.map,
+                                lambda s: _shape(one_chip, s.shape, s.dtype))
+    params, caches = on_chip(params), on_chip(caches)
+    tables = _shape(one_chip, (cfg.num_layers, Ev), jnp.int32)
+    nbytes = lambda tree: sum(s.size * s.dtype.itemsize  # noqa: E731
+                              for s in jax.tree.leaves(tree))
+    if which == "prefill":
+        tokens = _shape(one_chip, (1, 1024), jnp.int32)
+        step = jax.jit(lambda p, t, pl: prefill(p, {"tokens": t}, cfg,
+                                                policy, pl))
+        return step.lower(params, tokens, tables).compile(), nbytes(caches)
+    if which == "decode":
+        B, n_max = CELL_SLOTS, CELL_N_MAX
+        step = jax.jit(
+            lambda p, c, cl, tb, tk, pl: decode_step(
+                p, c, cl, tk, cfg, policy, pl, block_tables=tb),
+            donate_argnums=(1,))
+        return step.lower(
+            params, caches, _shape(one_chip, (B,), jnp.int32),
+            _shape(one_chip, (B, n_max), jnp.int32),
+            _shape(one_chip, (B, 1), jnp.int32), tables).compile(), 0
+    # the engine's apply: the three stacks donated, the router tables
+    # swapped in the same program
+    moe = params["blocks"]["moe"]
+    ex = MigrationExecutable()
+    apply = jax.jit(ex._host_apply, donate_argnums=(2, 3, 4))
+    rest = nbytes(params) - nbytes([moe[n] for n in ("w_gate", "w_up",
+                                                     "w_down")])
+    return apply.lower(tables, tables, moe["w_gate"], moe["w_up"],
+                       moe["w_down"]).compile(), rest + nbytes(caches)
+
+
+# one layer of one expert weight, in bytes
+MIXTRAL_LAYER_WEIGHT = (MIXTRAL.num_experts * MIXTRAL.d_model
+                        * MIXTRAL.expert_d_ff * 2)
+
+
+def test_mixtral_prefill_reads_expert_weights_in_place(mixtral_programs):
+    """The 1024-token prefill hands the expert kernel the stacked weights
+    and the scanned layer index: no instruction slices or copies one
+    layer's (16, 4096, 7168) weights out of the stack, where each would be
+    a 0.94 GB temporary and a full extra read and write a prefill."""
+    compiled, _ = mixtral_programs["prefill"]
+    text = compiled.as_text()
+    shapes = expert_weight_shapes(MIXTRAL_4L, 1)
+    assert "tpu_custom_call" in text
+    assert arrays_shaped(text, "bf16", shapes)  # the stacks are found
+    assert arrays_shaped(text, "bf16", shapes, opcodes=WEIGHT_MOVES) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        MIXTRAL_LAYER_WEIGHT)
+
+
+def test_mixtral_migration_apply_rewrites_one_layer_at_a_time(
+        mixtral_programs):
+    """The migration apply gathers one layer's rows of one weight at a
+    time and writes them over that layer in place: no instruction slices
+    or copies a layer out of the stacks, the three donated stacks alias
+    the outputs, and the temporaries hold no more than one layer of one
+    weight (the whole-stack gather held 5.2 GB)."""
+    compiled, _ = mixtral_programs["migrate"]
+    text = compiled.as_text()
+    shapes = expert_weight_shapes(MIXTRAL_4L, 1)
+    moves = ("dynamic-slice", "slice", "copy", "copy-start", "copy-done")
+    assert arrays_shaped(text, "bf16", shapes, opcodes=moves) == []
+    aliased = aliased_parameters(text)
+    assert len(arrays_shaped("\n".join(aliased), "bf16", shapes)) == 3
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= MIXTRAL_LAYER_WEIGHT * 1.01
+
+
+def test_granite_migration_apply_holds_one_layer_at_most(one_chip):
+    """At granite's widths too (80 small rows a layer, 32 layers) the
+    apply's temporaries stay within one layer of one weight, and the
+    three donated stacks alias the outputs: a one-op gather of each
+    layer's rows makes XLA copy the whole stack here."""
+    from repro.kernels.collective import MigrationExecutable
+
+    Ev = GRANITE.num_experts * GRANITE.expert_tp
+    params, _ = abstract_params(GRANITE, host_policy(), jnp.bfloat16)
+    moe = {n: _shape(one_chip, s.shape, s.dtype)
+           for n, s in params["blocks"]["moe"].items()}
+    tables = _shape(one_chip, (GRANITE.num_layers, Ev), jnp.int32)
+    apply = jax.jit(MigrationExecutable()._host_apply,
+                    donate_argnums=(2, 3, 4))
+    compiled = apply.lower(tables, tables, moe["w_gate"], moe["w_up"],
+                           moe["w_down"]).compile()
+    layer = moe["w_gate"].size // GRANITE.num_layers * 2
+    shapes = expert_weight_shapes(GRANITE, 1)
+    aliased = aliased_parameters(compiled.as_text())
+    assert len(arrays_shaped("\n".join(aliased), "bf16", shapes)) == 3
+    assert compiled.memory_analysis().temp_size_in_bytes <= layer * 1.01
+
+
+@pytest.mark.parametrize("which", ["prefill", "decode", "migrate"])
+def test_mixtral_programs_fit_one_chip_with_the_cells_pool(mixtral_programs,
+                                                           which):
+    """Each 4-layer program's arguments, outputs that alias none of them,
+    and temporaries, beside whatever of the weights and the cell's paged
+    pool it does not take, fit in one chip's memory."""
+    compiled, others = mixtral_programs[which]
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes + others)
+    assert held < CHIP_BYTES, held / 1e9
