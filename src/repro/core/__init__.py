@@ -42,7 +42,13 @@ from .score import (
     step_cost_matrix,
     step_token_matrix,
 )
-from .search import SearchResult, gem_place, initial_mapping, refine
+from .search import (
+    SearchResult,
+    gem_place,
+    initial_mapping,
+    refine,
+    refine_step_batch,
+)
 from .simulate import SimulationResult, latency_reduction, simulate_serving
 from .trace import TraceCollector
 from .types import ExpertTrace, GEMConfig, Placement, VariabilityProfile
@@ -70,6 +76,7 @@ __all__ = [
     "IncrementalScorer", "score", "per_step_latency", "step_cost_matrix",
     "step_token_matrix",
     "SearchResult", "gem_place", "initial_mapping", "refine",
+    "refine_step_batch",
     # online adaptation hooks
     "MigrationCostModel", "migration_net_benefit", "BandwidthEstimator",
     # step 4 / orchestration

@@ -17,7 +17,13 @@ import numpy as np
 from .score import IncrementalScorer, score
 from .types import ExpertTrace, GEMConfig, Placement, VariabilityProfile
 
-__all__ = ["SearchResult", "initial_mapping", "refine", "gem_place"]
+__all__ = [
+    "SearchResult",
+    "initial_mapping",
+    "refine",
+    "refine_step_batch",
+    "gem_place",
+]
 
 
 @dataclasses.dataclass
@@ -93,6 +99,127 @@ def refine(
         if drop / max(prev, 1e-30) < tol:
             break  # converged (< 0.1% relative improvement)
     return scorer.placement(), cur, swaps
+
+
+def _curve_table(profile: VariabilityProfile, max_tokens: int) -> np.ndarray:
+    """(G, W) device curves at integer token counts ``0 .. W-1``, by the
+    same ``np.interp`` the scorer uses, so a lookup is bit-identical.
+    ``np.interp`` holds the last sample flat, so the table stops at the
+    grid's end and larger counts read its last column."""
+    xp = profile.token_counts.astype(np.float64)
+    width = int(min(max_tokens, max(int(profile.token_counts[-1]), 0))) + 1
+    grid = np.arange(width, dtype=np.float64)
+    return np.stack([np.interp(grid, xp, fp) for fp in profile.latencies])
+
+
+def refine_step_batch(
+    device_of: np.ndarray,
+    counts: np.ndarray,
+    profile: VariabilityProfile,
+    *,
+    tol: float = 1e-3,
+    max_swaps: int = 200,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+    """:func:`refine` over B independent one-step traces at once.
+
+    ``device_of`` (B, E): each problem's balanced seed placement;
+    ``counts`` (B, E): its single step's expert token counts (cast to
+    int64, as :class:`ExpertTrace` does). Returns ``(device_of, scores,
+    swaps, rounds)``: per problem exactly the placement, score and swap
+    count ``refine(seed, one-step trace, profile, tol=tol,
+    max_swaps=max_swaps)`` gives, and the number of batched rounds run.
+
+    With one step the score is the straggler's cost, so a swap that
+    touches neither device at the (first) maximum leaves it standing and
+    cannot improve. Each round therefore scores only the pairs between
+    that device's experts and the rest, for every live problem in one
+    pass. Among the pairs of least score it picks the first in
+    ``triu_indices`` order, as ``best_swap``'s ``argmin`` does, and stops
+    a problem by :func:`refine`'s rules; stopped problems leave the batch.
+    """
+    dev = np.array(device_of, dtype=np.int32)
+    cnt = np.asarray(counts).astype(np.int64)
+    if dev.ndim != 2 or dev.shape != cnt.shape:
+        raise ValueError("device_of and counts must be (problems, experts)")
+    B, E = dev.shape
+    G = profile.num_devices
+    per_device = (dev[:, :, None] == np.arange(G)).sum(axis=1)
+    if E % G or (per_device != E // G).any():
+        raise ValueError("every seed must give each device E/G experts")
+    K = E // G
+    rows = np.arange(B)
+    tokens = np.zeros((B, G), dtype=np.int64)
+    np.add.at(tokens, (rows[:, None], dev), cnt)
+    table = _curve_table(profile, int(tokens.sum(axis=1).max(initial=0)))
+    top = table.shape[1] - 1
+    flat = table.ravel()
+
+    def cost(g, n):
+        return flat[g * (top + 1) + np.minimum(n, top)]
+
+    costs = cost(np.arange(G)[None, :], tokens)  # (B, G)
+    cur = costs.max(axis=1)
+    swaps = np.zeros(B, dtype=np.int64)
+    live = np.flatnonzero(np.full(B, max_swaps > 0))
+    rounds = 0
+    while live.size:
+        rounds += 1
+        b = live.size
+        d, c, tok, cst = dev[live], cnt[live], tokens[live], costs[live]
+        r = np.arange(b)
+        gs = cst.argmax(axis=1)  # the straggler, first on ties
+        # S: the straggler's experts, ascending (b, K)
+        S = np.nonzero(d == gs[:, None])[1].reshape(b, K)
+        cS = np.take_along_axis(c, S, axis=1)
+        # the max over devices other than the straggler and d, per d
+        rest = cst.copy()
+        rest[r, gs] = -np.inf
+        i1 = rest.argmax(axis=1)
+        v1 = rest[r, i1]
+        rest[r, i1] = -np.inf
+        v2 = rest.max(axis=1)
+        others = np.where(
+            np.arange(G)[None, :] == i1[:, None], v2[:, None], v1[:, None]
+        )
+        # pair (S[i], f): f's count moves onto the straggler, S[i]'s off it
+        delta = c[:, None, :] - cS[:, :, None]  # (b, K, E)
+        new_s = cost(gs[:, None, None], tok[r, gs][:, None, None] + delta)
+        new_f = cost(
+            d[:, None, :],
+            np.take_along_axis(tok, d, axis=1)[:, None, :] - delta,
+        )
+        # a partner on the straggler is no pair: its max reads +inf
+        others_f = np.take_along_axis(others, d, axis=1)
+        others_f[d == gs[:, None]] = np.inf
+        pair = np.maximum(others_f[:, None, :], np.maximum(new_s, new_f))
+        pair = pair.reshape(b, -1)
+        new = pair.min(axis=1)
+        # first pair in triu order among the least: smallest (lo, hi)
+        at, flat_at = np.nonzero(pair == new[:, None])
+        e_s, e_f = S[at, flat_at // E], flat_at % E
+        best = np.full(b, E * E)
+        np.minimum.at(
+            best, at, np.minimum(e_s, e_f) * E + np.maximum(e_s, e_f)
+        )
+        prev = cur[live]
+        improves = new < prev
+        go = live[improves]
+        lo, hi = best[improves] // E, best[improves] % E
+        g_lo, g_hi = dev[go, lo], dev[go, hi]
+        moved = cnt[go, hi] - cnt[go, lo]
+        tokens[go, g_lo] += moved
+        tokens[go, g_hi] -= moved
+        dev[go, lo], dev[go, hi] = g_hi, g_lo
+        costs[go, g_lo] = cost(g_lo, tokens[go, g_lo])
+        costs[go, g_hi] = cost(g_hi, tokens[go, g_hi])
+        swaps[go] += 1
+        cur[go] = new[improves]
+        drop = prev[improves] - new[improves]
+        keep = (drop / np.maximum(prev[improves], 1e-30) >= tol) & (
+            swaps[go] < max_swaps
+        )
+        live = go[keep]
+    return dev, cur, swaps, rounds
 
 
 def gem_place(

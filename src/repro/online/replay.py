@@ -272,7 +272,9 @@ def replay_online(
             ),
             lagging=controller.adapting,
         )
-        record_step_metrics(tel, sr, t)
+        record_step_metrics(
+            tel, sr, t, search_rounds=regret.last_search_rounds
+        )
         decision = controller.observe_step(counts, observed)
         if decision.migration_step is not None:
             lat += decision.migration_cost

@@ -1188,7 +1188,10 @@ class ServingEngine:
             ),
             lagging=lagging,
         )
-        record_step_metrics(self.telemetry, sr, self.step_count)
+        record_step_metrics(
+            self.telemetry, sr, self.step_count,
+            search_rounds=self.regret.last_search_rounds,
+        )
 
     def _maybe_replan(self) -> None:
         """The one-shot plan, once its trace window has filled."""

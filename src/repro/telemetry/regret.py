@@ -9,9 +9,11 @@ placements, and compute
 - ``actual_s``      — the step cost the run really paid,
   ``Σ_l max_g C_g(n_g)`` under the live placement;
 - ``oracle_s``      — the hindsight-oracle step cost: a warm-started GEM
-  re-search (:func:`repro.core.search.refine`) over *this step's own
-  loads*, seeded from the live placement and from the previous step's
-  oracle. Because refine only ever applies improving swaps, the oracle
+  re-search over *this step's own loads*, seeded from the live placement
+  and from the previous step's oracle, every layer's climb in one batched
+  search (:func:`repro.core.search.refine_step_batch`, exactly
+  :func:`~repro.core.search.refine` on a one-step trace). Because the
+  search only ever applies improving swaps, the oracle
   is never worse than the live placement on the step's loads, so
   ``regret = actual − oracle ≥ 0`` holds **by construction** (the
   replicated pool's split shares can beat any single-copy placement, so
@@ -43,8 +45,8 @@ import dataclasses
 import numpy as np
 
 from ..core.eplb import linear_placement
-from ..core.search import refine
-from ..core.types import ExpertTrace, Placement, VariabilityProfile
+from ..core.search import refine_step_batch
+from ..core.types import Placement, VariabilityProfile
 
 __all__ = [
     "NOISE_FLOOR",
@@ -86,12 +88,17 @@ class StepRegret:
         return self.oracle_s - self.lower_bound_s
 
 
-def record_step_metrics(telemetry, sr: StepRegret, step: int) -> None:
+def record_step_metrics(
+    telemetry, sr: StepRegret, step: int, *, search_rounds: int = 0
+) -> None:
     """Mirror one step's regret onto a telemetry hub: cumulative
     counters + the per-step histogram (always recorded — registry
     instruments are pure host state) and a ``regret`` instant for the
     report's timeline (event-gated). All quantities are non-negative by
-    construction, so counters fit."""
+    construction, so counters fit. ``search_rounds``: the rounds of the
+    oracle's batched search this step
+    (:attr:`RegretTracker.last_search_rounds`)."""
+    telemetry.counter("regret.search_rounds").inc(search_rounds)
     telemetry.counter("regret.actual_s").inc(sr.actual_s)
     telemetry.counter("regret.oracle_s").inc(sr.oracle_s)
     telemetry.counter("regret.lower_bound_s").inc(sr.lower_bound_s)
@@ -137,7 +144,9 @@ class RegretTracker:
         self.num_devices = int(num_devices)
         self.tol = float(tol)
         self.max_swaps = int(max_swaps)
-        self._warm: dict[int, Placement] = {}  # layer → last oracle placement
+        # (L, E) last oracle placement per layer
+        self._warm: np.ndarray | None = None
+        self.last_search_rounds = 0  # batched search rounds of the last step
         self.steps = 0
         self.sum_actual = 0.0
         self.sum_oracle = 0.0
@@ -147,40 +156,46 @@ class RegretTracker:
         self.series: list[StepRegret] | None = [] if keep_series else None
 
     # -- oracle --------------------------------------------------------
-    def _oracle_layer(
+    def _oracle(
         self,
-        layer: int,
         counts: np.ndarray,
         profile: VariabilityProfile,
-        live: Placement | None,
+        placements: list[Placement] | None,
     ) -> float:
-        """Hindsight re-search of one layer's loads: hill-climb from the
-        live placement and from the previous step's oracle, keep the best.
-        The warm pair makes the per-step search a handful of swaps — the
-        oracle placement barely moves between adjacent steps."""
-        trace = ExpertTrace(counts[None, :].astype(np.int64))
-        seeds: list[Placement] = []
-        if live is not None:
-            seeds.append(live)
-        prev = self._warm.get(layer)
-        if prev is not None and not any(
-            np.array_equal(prev.expert_to_device, s.expert_to_device)
-            for s in seeds
-        ):
-            seeds.append(prev)
-        if not seeds:
-            seeds.append(linear_placement(self.num_experts, self.num_devices))
-        best_p: Placement | None = None
-        best_s = np.inf
-        for seed in seeds:
-            p, s, _ = refine(
-                seed, trace, profile, tol=self.tol, max_swaps=self.max_swaps
+        """Hindsight re-search of every layer's loads: hill-climb from the
+        live placement and from the previous step's oracle, keep the best
+        (the live one on ties). The warm pair makes the per-step search a
+        handful of swaps — the oracle placement barely moves between
+        adjacent steps. All layers' climbs run as one batched search."""
+        L = counts.shape[0]
+        warm = np.zeros(0, dtype=np.int64)  # layers whose warm seed differs
+        if placements is not None:
+            seeds = np.stack([p.expert_to_device for p in placements[:L]])
+            if self._warm is not None:
+                warm = np.flatnonzero((self._warm != seeds).any(axis=1))
+        elif self._warm is not None:
+            seeds = self._warm
+        else:
+            seeds = np.tile(
+                linear_placement(
+                    self.num_experts, self.num_devices
+                ).expert_to_device,
+                (L, 1),
             )
-            if s < best_s:
-                best_p, best_s = p, s
-        assert best_p is not None
-        self._warm[layer] = best_p
-        return float(best_s)
+        device_of, scores, _, rounds = refine_step_batch(
+            np.concatenate([seeds, self._warm[warm]]) if warm.size else seeds,
+            np.concatenate([counts, counts[warm]]),
+            profile,
+            tol=self.tol,
+            max_swaps=self.max_swaps,
+        )
+        best, best_s = device_of[:L], scores[:L]
+        wins = scores[L:] < best_s[warm]
+        best[warm[wins]] = device_of[L:][wins]
+        best_s[warm[wins]] = scores[L:][wins]
+        self._warm = best
+        self.last_search_rounds = rounds
+        return sum(best_s.tolist())
 
     def _lower_bound(
         self, counts: np.ndarray, profile: VariabilityProfile
@@ -214,15 +229,7 @@ class RegretTracker:
         flight / deferred / warm-up) so the gap is migration lag.
         """
         counts = np.atleast_2d(np.asarray(counts))
-        searched = sum(
-            self._oracle_layer(
-                layer,
-                counts[layer],
-                profile,
-                placements[layer] if placements is not None else None,
-            )
-            for layer in range(counts.shape[0])
-        )
+        searched = self._oracle(counts, profile, placements)
         actual_s = float(actual_s)
         # the live placement is always a hindsight candidate ("do nothing"),
         # so the oracle can never exceed what the run paid — this clamp is

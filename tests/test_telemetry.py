@@ -465,6 +465,13 @@ def test_engine_step_counters_and_attribution(engine_pair):
     assert all(isinstance(v, float) for v in rep.values())
 
 
+def test_engine_records_regret_search_rounds(engine_pair):
+    eng, _ = engine_pair["on"]
+    snap = eng.telemetry.registry.snapshot()
+    assert eng.regret.steps > 0
+    assert snap["counters"]["regret.search_rounds"] >= 0
+
+
 # ---------------------------------------------------------------------------
 # placement regret (hindsight oracle)
 # ---------------------------------------------------------------------------
